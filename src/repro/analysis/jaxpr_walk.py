@@ -1,6 +1,6 @@
 """Recursive jaxpr traversal with trip-count multipliers.
 
-Serving programs nest: pjit wrappers, the T-micro-step ``lax.scan`` of a
+Serving programs nest: jit wrappers, the T-micro-step ``lax.scan`` of a
 decode block, vmapped cache writes, cond branches. Every verifier pass
 that counts or sizes eqns (routed hops, callbacks, DUS writes) must see
 through that nesting AND weight body eqns by how often they run — a hop
@@ -16,12 +16,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Iterator, List, Optional, Tuple
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 
 @dataclass(frozen=True)
 class EqnSite:
-    eqn: Any               # jax.core.JaxprEqn
+    eqn: Any               # jax.extend.core.JaxprEqn
     trips: int             # product of enclosing static scan lengths
     unbounded: bool        # inside a while body (trips is a lower bound)
 
@@ -62,13 +62,13 @@ def iter_eqns(jaxpr, trips: int = 1, unbounded: bool = False) \
             yield from iter_eqns(sub, sub_trips, sub_unbounded)
 
 
-def named_pjit_sites(jaxpr, names) -> List[Tuple[str, EqnSite]]:
-    """(name, site) for every pjit eqn whose name is in ``names`` — the
+def named_jit_sites(jaxpr, names) -> List[Tuple[str, EqnSite]]:
+    """(name, site) for every nested jit eqn whose name is in ``names`` — the
     anchor used by routing_check to find the tagged W↔A hop markers."""
     names = set(names)
     out = []
     for site in iter_eqns(jaxpr):
-        if site.eqn.primitive.name == "pjit" \
+        if site.eqn.primitive.name == "jit" \
                 and site.eqn.params.get("name") in names:
             out.append((site.eqn.params["name"], site))
     return out
@@ -95,5 +95,5 @@ def aval_bytes(aval) -> int:
     return int(np.prod(aval.shape, dtype=np.int64)) * aval.dtype.itemsize
 
 
-__all__ = ["EqnSite", "iter_eqns", "named_pjit_sites", "primitive_sites",
+__all__ = ["EqnSite", "iter_eqns", "named_jit_sites", "primitive_sites",
            "literal_value", "aval_bytes"]

@@ -29,7 +29,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Report
 from repro.analysis.jaxpr_walk import iter_eqns, literal_value
@@ -50,8 +50,7 @@ def _eval_index_map(bm, idx: Tuple[int, ...]) -> Optional[Tuple[int, ...]]:
     if im is None:
         return None
     try:
-        out = jax_core.eval_jaxpr(im.jaxpr, im.consts,
-                                  *[np.int32(i) for i in idx])
+        out = jax_core.jaxpr_as_fun(im)(*[np.int32(i) for i in idx])
         return tuple(int(x) for x in out)
     except Exception:
         return None
@@ -94,7 +93,9 @@ def check_pallas_sites(jaxpr, program: str, report: Report,
 
 def _check_coverage(program: str, report: Report, op_i: int, aval,
                     bm, pts: List[Tuple[int, ...]]):
-    bshape = tuple(1 if b is None else int(b)
+    # block dims are pl.Blocked(n) or pl.Squeezed() (block_size None): a
+    # squeezed dim tiles by 1
+    bshape = tuple(int(getattr(b, "block_size", None) or 1)
                    for b in getattr(bm, "block_shape", ()))
     if len(bshape) != len(aval.shape) or not pts:
         return

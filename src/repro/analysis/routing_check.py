@@ -6,7 +6,7 @@ exactly ``2 × n_layers`` W↔A hops per micro-step — 3 W→A (q,k,v) and
 ``WABackend.expected_routing`` / ``core.wa.routing_bytes`` claims precisely
 those bytes. This pass recomputes the hop traffic FROM THE PROGRAM: it
 walks the jaxpr for the tagged hop markers (``wa_hop_to_a`` /
-``wa_hop_to_w`` pjit eqns, scan-trip-weighted) and fails on any drift —
+``wa_hop_to_w`` jit eqns, scan-trip-weighted) and fails on any drift —
 a dropped hop (a layer silently bypassing the A domain), an extra hop, or
 a meter constant that no longer matches what the compiled program moves.
 
@@ -32,7 +32,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.analysis.findings import Report
-from repro.analysis.jaxpr_walk import named_pjit_sites
+from repro.analysis.jaxpr_walk import named_jit_sites
 from repro.analysis.programs import Cell
 from repro.core.wa import WA_HOP_TO_A, WA_HOP_TO_W, routing_bytes
 
@@ -42,7 +42,7 @@ PASS = "routing_check"
 def _hop_stats(jaxpr):
     """{tag: (weighted_count, weighted_bytes, dtypes)} over tagged hops."""
     stats = {WA_HOP_TO_A: [0, 0, set()], WA_HOP_TO_W: [0, 0, set()]}
-    for tag, site in named_pjit_sites(jaxpr, stats):
+    for tag, site in named_jit_sites(jaxpr, stats):
         aval = site.eqn.invars[0].aval
         nbytes = int(np.prod(aval.shape, dtype=np.int64))\
             * aval.dtype.itemsize

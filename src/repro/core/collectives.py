@@ -21,15 +21,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.compat import axis_size
-
 
 def hierarchical_psum(x: jax.Array, fast_axis: str, slow_axis: str,
                       scatter_dim: int = 0) -> jax.Array:
     """psum over (fast_axis × slow_axis) with slow-link traffic ÷ fast_size.
     Requires x.shape[scatter_dim] % fast_size == 0 (falls back to flat psum
     otherwise)."""
-    fast = axis_size(fast_axis)
+    fast = lax.axis_size(fast_axis)
     if x.shape[scatter_dim] % fast != 0:
         return lax.psum(x, (fast_axis, slow_axis))
     shard = lax.psum_scatter(x, fast_axis, scatter_dimension=scatter_dim,
@@ -39,14 +37,14 @@ def hierarchical_psum(x: jax.Array, fast_axis: str, slow_axis: str,
 
 
 def hierarchical_pmean(x, fast_axis: str, slow_axis: str, scatter_dim: int = 0):
-    total = axis_size(fast_axis) * axis_size(slow_axis)
+    total = lax.axis_size(fast_axis) * lax.axis_size(slow_axis)
     return hierarchical_psum(x, fast_axis, slow_axis, scatter_dim) / total
 
 
 def ring_all_gather(x: jax.Array, axis: str, concat_dim: int = 0) -> jax.Array:
     """Explicit ring all-gather via ppermute (fan-in 2 per step) — the
     shard_map building block when we schedule collectives by hand."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
     pieces = [x]

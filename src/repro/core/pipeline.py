@@ -60,10 +60,9 @@ def _pod_specs(tree):
 def _shard_map(f, mesh, in_specs, out_specs):
     """Partial-manual shard_map: manual over 'pod', auto over data/model —
     inner GSPMD rules keep working while we schedule the pipeline by hand."""
-    from repro.core.compat import shard_map
-    return shard_map(f, mesh=mesh, in_specs=_pod_specs(in_specs),
-                     out_specs=_pod_specs(out_specs),
-                     axis_names=frozenset({"pod"}), check_vma=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=_pod_specs(in_specs),
+                         out_specs=_pod_specs(out_specs),
+                         axis_names=frozenset({"pod"}), check_vma=False)
 
 
 def stage_params(params: Dict[str, Any], n_stages: int) -> Dict[str, Any]:

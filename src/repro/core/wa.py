@@ -151,7 +151,7 @@ def micro_batch_slices(batch: int, depth: int) -> Tuple[slice, ...]:
 # which on reduced test configs can degrade to a replicated spec (e.g. 4
 # heads on an 8-wide model axis) and become indistinguishable from any other
 # annotation in the jaxpr. Wrapping each hop in a named inner jit gives the
-# static verifier (repro.analysis.routing_check) a stable anchor: a ``pjit``
+# static verifier (repro.analysis.routing_check) a stable anchor: a ``jit``
 # eqn whose name is WA_HOP_TO_A / WA_HOP_TO_W, regardless of how the spec
 # degraded. Semantically identical to the bare constraint.
 
@@ -699,6 +699,9 @@ class WADisaggregated:
         logits = common.unembed_logits(unembed_table(params, cfg), last,
                                        self.w_ctx)
         new_len = jnp.maximum(cache.length, start + valid_len)
+        # exit pin too: the donated stacks must leave in the layout they
+        # entered with, or the donation degrades to a copy per dispatch
+        stacks = self._pin_cache_stacks(*stacks)
         return cache._replace(k=stacks[0], v=stacks[1], k_scale=stacks[2],
                               v_scale=stacks[3], hot_k=stacks[4],
                               hot_v=stacks[5], length=new_len), logits

@@ -10,5 +10,31 @@
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper
 with platform dispatch), ref.py (pure-jnp oracle used by tests and by the CPU
-dry-run path).
+dry-run path). The serving path calls none of them yet: its attention and
+matmuls are the jnp forms in ``models/`` and ``core/wa.py``.
 """
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+
+
+def use_pallas_kernel(use_pallas: Optional[bool], interpret: bool) -> bool:
+    """Whether an ops wrapper runs its Pallas kernel.
+
+    ``interpret=True`` runs the kernel in interpret mode on any backend;
+    ``use_pallas=None`` picks the kernel on a TPU and the jnp oracle
+    elsewhere. ``use_pallas=True`` off a TPU raises: the kernel never
+    drops into interpret mode unless the caller asked for it."""
+    if interpret:
+        return True
+    on_tpu = jax.default_backend() == "tpu"
+    if use_pallas is None:
+        return on_tpu
+    if use_pallas and not on_tpu:
+        raise ValueError(
+            f"use_pallas=True needs a TPU (backend is "
+            f"{jax.default_backend()!r}); pass interpret=True to run the "
+            "kernel in interpret mode")
+    return use_pallas

@@ -56,8 +56,8 @@ def _kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref, lim_ref,
             v = v * vs_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = mask_ref[0]                               # (S_blk,)
-        s = jnp.where(mask[None, :], s, NEG_INF)
+        mask = mask_ref[0]                               # (1, S_blk)
+        s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[0, 0]                             # (G, 1)
         m_blk = jnp.max(s, axis=1, keepdims=True)
@@ -114,6 +114,9 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         k_scale = jnp.ones((B, n_kv, 1, 1), jnp.float32)
         v_scale = jnp.ones((B, n_kv, 1, 1), jnp.float32)
     ss = k_scale.shape[2]
+    # (B, 1, S): a (1, 1, bs) block keeps the last two block dims at
+    # (full, multiple of 128) — Mosaic refuses a (1, bs) block of (B, S)
+    mask = mask.reshape(B, 1, S)
     if kv_limit is None:
         kv_limit = jnp.full((1, 1), S, jnp.int32)
     else:
@@ -134,7 +137,7 @@ def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, bs if quantized else ss, 1),
                          (lambda b, h, s: (b, h, s, 0)) if quantized
                          else (lambda b, h, s: (b, h, 0, 0))),
-            pl.BlockSpec((1, bs), lambda b, h, s: (b, s)),
+            pl.BlockSpec((1, 1, bs), lambda b, h, s: (b, 0, s)),
             pl.BlockSpec((1, 1), lambda b, h, s: (0, 0)),
         ],
         out_specs=[

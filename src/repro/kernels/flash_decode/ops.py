@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import use_pallas_kernel
 from repro.kernels.flash_decode.combine import (combine_partial_stats,
                                                 merge_partial_stats)
 from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
@@ -14,10 +15,6 @@ from repro.kernels.flash_decode.ref import (flash_decode_ref,
 
 __all__ = ["flash_decode", "flash_decode_partial", "combine_partial_stats",
            "merge_partial_stats"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
@@ -30,12 +27,9 @@ def flash_decode(q, k, v, mask, k_scale=None, v_scale=None, *,
     ``kv_limit`` (optional, traced int32): max live KV extent — the Pallas
     kernel skips tiles wholly past it (length-aware walk); the jnp reference
     applies it as a mask cut so both paths agree numerically."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if use_pallas_kernel(use_pallas, interpret):
         return flash_decode_pallas(q, k, v, k_scale, v_scale, mask,
-                                   block_s=block_s,
-                                   interpret=interpret or not _on_tpu(),
+                                   block_s=block_s, interpret=interpret,
                                    kv_limit=kv_limit)
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale
@@ -54,12 +48,9 @@ def flash_decode_partial(q, k, v, mask, k_scale=None, v_scale=None, *,
     ``combine_partial_stats`` merge. ``kv_limit`` here is the SHARD-LOCAL
     live extent; a shard with ``kv_limit <= 0`` yields the merge identity
     ``(0, NEG_INF, 0)`` on both paths."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
+    if use_pallas_kernel(use_pallas, interpret):
         return flash_decode_pallas(q, k, v, k_scale, v_scale, mask,
-                                   block_s=block_s,
-                                   interpret=interpret or not _on_tpu(),
+                                   block_s=block_s, interpret=interpret,
                                    kv_limit=kv_limit, partial_stats=True)
     if k_scale is not None:
         k = k.astype(jnp.float32) * k_scale

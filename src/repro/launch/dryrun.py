@@ -85,8 +85,7 @@ def _probe_cost(cfg, shape, multi_pod, executor, pod_strategy):
                                    pod_strategy=pod_strategy)
                 lowered = bundle.lower()
                 compiled = lowered.compile()
-                from repro.core.compat import cost_analysis
-                cost = cost_analysis(compiled)
+                cost = compiled.cost_analysis()
                 coll = parse_collectives(compiled.as_text(),
                                          mesh.devices.shape, mesh.axis_names)
             vals.append({

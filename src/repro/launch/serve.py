@@ -4,6 +4,9 @@
         --requests 8 --batch 4 --prompt-len 32 --max-new 16 --reduced \
         --arrival-every 4
 
+``--no-reduced`` serves the architecture's published widths (on a chip:
+``chip_smoke.py`` at the repo root is that run for ``qwen2-0.5b``).
+
 Reports the paper's metrics (TPOT mean/p50/p99, throughput) plus the
 scheduler-side metrics the continuous engine adds (per-request TTFT, queue
 delay, overlapped admissions) from real measured steps on this host (reduced
@@ -16,6 +19,8 @@ comparison (late arrivals starve until the whole batch empties — DESIGN.md §7
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 import numpy as np
 
@@ -23,6 +28,23 @@ from repro.configs.registry import get_config
 from repro.models.registry import build_model
 from repro.models.sharding import ShardingCtx, operator_centric, sub_operator
 from repro.runtime.serving import Request, ServingEngine
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    read by JAX itself and nothing is set here; otherwise the cache lives at
+    the fixed ``<repo root>/.jax_cache`` (the path is part of each entry's
+    key, so it must not move between runs)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_requests(cfg, n_requests: int, prompt_len: int, max_new: int,
@@ -47,6 +69,7 @@ def serve(arch: str, n_requests: int, batch_slots: int, prompt_len: int,
           preemptible: bool = False, max_queue: int = 0,
           hot_window: int = 0, kv_cold_dtype: str = "int8",
           kv_cold_block: int = 16, kv_budget_bytes: int = 0):
+    enable_compile_cache()
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
@@ -84,7 +107,10 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="tiny widths of the same architecture (default); "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--mode", default="auto",
                     choices=("auto", "continuous", "drain"))
     ap.add_argument("--arrival-every", type=int, default=0,

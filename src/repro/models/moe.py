@@ -165,12 +165,11 @@ def _moe_ffn_sharded(p: Dict, x: jax.Array, cfg: ModelConfig,
         return out, jax.lax.pmean(aux, dp_axes)
 
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
     x_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0], None, None)
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(x_spec, P()),
-                  out_specs=(x_spec, P()),
-                  axis_names=frozenset(dp_axes), check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(x_spec, P()),
+                      out_specs=(x_spec, P()),
+                      axis_names=frozenset(dp_axes), check_vma=False)
     return f(x, p)
 
 

@@ -88,7 +88,7 @@ from repro.kv.cache import (KVCache, cold_boundary, export_slot_kv,
                             import_slot_kv)
 from repro.models.attention import bucket_for, kv_buckets
 from repro.models.common import dtype_of
-from repro.models.param_specs import cache_specs
+from repro.models.param_specs import cache_specs, param_specs
 from repro.models.registry import DECODE_SLACK, ModelAPI
 from repro.models.sharding import ShardingCtx
 from repro.runtime.static_runtime import DispatchError, StaticRuntime
@@ -683,6 +683,11 @@ class ExecutorBackend:
                 jnp.asarray(remaining), jnp.asarray(eos))
         return toks, emitted, last_d, pos_d, act_d, rem_d
 
+    def place_params(self, params):
+        """``params`` placed as the step programs take them. The colocated
+        programs take the caller's placement as it is."""
+        return params
+
     def reset(self, slot: int):
         self.caches = self._reset(self.caches, jnp.asarray(slot, jnp.int32))
 
@@ -859,6 +864,19 @@ class WABackend(ExecutorBackend):
         self._el = jnp.dtype(dtype_of(api.config)).itemsize
         self.routed_bytes = 0
         scalar = jnp.zeros((), jnp.int32)
+        # weights live under the W-domain rules on the serving mesh: the
+        # programs compile for that placement and place_params() moves the
+        # caller's tree there once per run (a tree left on one device would
+        # be re-sharded by every dispatch)
+        self._w_shardings = None
+        if ctx.mesh is not None:
+            self._w_shardings = jax.tree.map(
+                lambda s: jax.sharding.NamedSharding(ctx.mesh, s),
+                param_specs(params, self.wa.w_ctx))
+            params = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=s),
+                params, self._w_shardings)
 
         def chunk_fn(p, caches, toks, slot, start, valid):
             caches, logits = self.wa.prefill_chunk(p, caches, toks, slot,
@@ -906,6 +924,11 @@ class WABackend(ExecutorBackend):
                                                    self._el)
 
     # -- execution (adds the W↔A traffic meter) ---------------------------
+    def place_params(self, params):
+        if self._w_shardings is None:
+            return params
+        return jax.device_put(params, self._w_shardings)
+
     def fresh(self):
         super().fresh()
         self.routed_bytes = 0
@@ -1377,7 +1400,9 @@ class ServingEngine:
         self.fault_injector = fault_injector
         self.rt = runtime or StaticRuntime()
         self.queue: List[Request] = []
-        self._params = None
+        # the weights as the step programs take them (after the backend
+        # placed them) — set by every run()
+        self.params = None
         self._ex: Optional[ExecutorBackend] = None
         # the ONE derivation of the slot-cache aval: the executor compiles
         # against it and the KV-extent admission bound reads off it
@@ -1563,9 +1588,6 @@ class ServingEngine:
         out = tuple(np.asarray(a) for a in arrays)
         return out if len(out) > 1 else out[0]
 
-    def load(self, params):
-        self._params = params
-
     def _validate_request(self, r: Request):
         """Admission-time length contract — the silent-truncation fix: a
         prompt the engine cannot represent is REJECTED here, never cut.
@@ -1638,13 +1660,13 @@ class ServingEngine:
         (never silently dropped). Reusable: each call starts from fresh
         caches and fresh accumulators (AOT programs persist — zero
         recompilation across runs)."""
-        self.load(params)
         pre = list(self.queue)
         seen = {id(r) for r in pre}
         requests = pre + [r for r in requests if id(r) not in seen]
         for r in requests:
             self._validate_request(r)
         self._prepare(params)
+        self.params = params = self._ex.place_params(params)
         self._reset_per_run()
         # fault-injection hook: installed (or cleared) per run so a clean
         # reference run on the same engine sees zero injected faults

@@ -68,8 +68,7 @@ class CompiledStep:
         return self.compiled(*args)
 
     def cost_analysis(self):
-        from repro.core.compat import cost_analysis
-        return cost_analysis(self.compiled)
+        return self.compiled.cost_analysis()
 
     def memory_analysis(self):
         return self.compiled.memory_analysis()

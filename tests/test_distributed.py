@@ -38,7 +38,6 @@ def test_executors_differ_operator_centric_pays_in_bytes():
     from jax.sharding import Mesh
     from repro.configs.registry import get_config
     from repro.configs.shapes import ShapeConfig
-    from repro.core.compat import cost_analysis
     from repro.core.execution import make_step
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
@@ -48,7 +47,7 @@ def test_executors_differ_operator_centric_pays_in_bytes():
     for ex in ("operator_centric", "sub_operator"):
         b = make_step(cfg, shape, mesh, executor=ex)
         comp = b.lower().compile()
-        res[ex] = cost_analysis(comp).get("bytes accessed", 0.0)
+        res[ex] = comp.cost_analysis().get("bytes accessed", 0.0)
     print("RESULT", res["operator_centric"], res["sub_operator"])
     assert res["operator_centric"] >= res["sub_operator"], res
     """)
@@ -76,8 +75,7 @@ def test_sharded_decode_matches_single_device():
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
     ctx = ShardingCtx(mesh, sub_operator())
-    with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") \
-            else mesh:
+    with mesh:
         c1, _ = jax.jit(lambda p, b: api.prefill(p, b, ctx))(
             params, {"tokens": toks[:, :S]})
         _, got = jax.jit(lambda p, c, t: api.decode(p, c, t, ctx))(
@@ -93,7 +91,6 @@ def test_hierarchical_psum_correct_and_cheaper_cross_pod():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core.collectives import hierarchical_psum
-    from repro.core.compat import shard_map
     from repro.launch.hlo_analysis import parse_collectives
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 2),
@@ -110,10 +107,10 @@ def test_hierarchical_psum_correct_and_cheaper_cross_pod():
     byts = {}
     for name, fn in (("flat", flat), ("hier", hier)):
         # out stays replicated-per-shard: use full specs
-        f = jax.jit(shard_map(fn, mesh=mesh,
-                              in_specs=P(("pod", "data"), None),
-                              out_specs=P(),
-                              check_vma=False))
+        f = jax.jit(jax.shard_map(fn, mesh=mesh,
+                                  in_specs=P(("pod", "data"), None),
+                                  out_specs=P(),
+                                  check_vma=False))
         lowered = f.lower(x)
         comp = lowered.compile()
         outs[name] = np.asarray(comp(x))

@@ -17,6 +17,7 @@ from repro.kernels.flash_decode.ref import (flash_decode_ref,
 from repro.kernels.fused_ffn.ops import fused_ffn
 from repro.kernels.fused_ffn.ref import fused_ffn_ref
 from repro.kernels.gemv.gemv import gemv_int8_pallas
+from repro.kernels.gemv.ops import gemv_int8
 from repro.kernels.gemv.ref import gemv_int8_ref
 from repro.quant.int8 import quantize_int8, quantize_kv
 
@@ -307,3 +308,30 @@ def test_fused_ffn_sweep(B, D, F, bf, act):
                     out_dtype=jnp.float32)
     want = fused_ffn_ref(x, wg, wu, wd, act=act)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("op", ["flash_decode", "fused_ffn", "gemv_int8"])
+def test_pallas_off_tpu_raises_unless_interpreted(op):
+    """Asking for the Pallas kernel on a backend that is not a TPU raises:
+    the kernel runs in interpret mode only when the caller passes
+    interpret=True, never as a silent fallback."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the kernel runs natively on a TPU")
+    x = jnp.ones((2, 128), jnp.float32)
+    w = jnp.ones((128, 256), jnp.float32) * 0.01
+    calls = {
+        "flash_decode": lambda **kw: flash_decode(
+            jnp.ones((1, 2, 16)), jnp.ones((1, 1, 32, 16)),
+            jnp.ones((1, 1, 32, 16)), jnp.ones((1, 32), bool), block_s=32,
+            **kw),
+        "fused_ffn": lambda **kw: fused_ffn(x, w, w, w.T, block_f=128,
+                                            out_dtype=jnp.float32, **kw),
+        "gemv_int8": lambda **kw: gemv_int8(x, quantize_int8(w, axis=0),
+                                            out_dtype=jnp.float32, **kw),
+    }
+    with pytest.raises(ValueError, match="interpret=True"):
+        calls[op](use_pallas=True)
+    got = calls[op](use_pallas=True, interpret=True)
+    want = calls[op](use_pallas=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-2, atol=1e-2)

@@ -34,6 +34,15 @@ def dense():
 
 
 @pytest.fixture(scope="module")
+def dense_f32():
+    # split-KV einsums take bf16 operands into an f32 accumulator, a dot the
+    # CPU backend does not implement; f32 keeps the programs compilable here
+    cfg = ASSIGNED["qwen2-0.5b"].reduced().replace(dtype="float32")
+    api = build_model(cfg)
+    return cfg, api, api.init(jax.random.key(0))
+
+
+@pytest.fixture(scope="module")
 def dense_int8():
     cfg = ASSIGNED["qwen2-0.5b"].reduced().replace(kv_dtype="int8")
     api = build_model(cfg)
@@ -131,20 +140,32 @@ def test_decode_block_halts_exactly_at_budget(dense):
     assert not np.asarray(act_o).any()
 
 
+def _first_new_at(stream, step):
+    """True when stream[step] occurs nowhere before it — an EOS id taken
+    from that step then halts exactly there (a random model often repeats
+    one token, which would halt at an earlier step)."""
+    return stream[step] not in list(stream[:step])
+
+
 def test_decode_block_eos_halts_on_device(dense):
     """Generate without EOS, pick the token emitted at micro-step 3, rerun
     with that id as the slot's EOS operand: the slot must emit it and halt
     — entirely on device, no host intervention."""
     cfg, api, params = dense
-    toks = jax.random.randint(jax.random.key(2), (2, PROMPT_LEN), 0,
-                              cfg.vocab_size)
-    c0, logits = api.prefill(params, {"tokens": toks}, NULL_CTX)
-    cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-    args = (cur, jnp.full((2,), PROMPT_LEN, jnp.int32),
-            jnp.array([True, True]), jnp.full((2,), T, jnp.int32))
     blk = jax.jit(lambda *xs: api.decode_block(*xs, NULL_CTX, block_size=T))
-    _, toks_free, _, _, _, _, _ = blk(params, c0, *args,
-                                      jnp.full((2,), -1, jnp.int32))
+    for seed in range(2, 34):          # a prompt whose step-3 token is new
+        toks = jax.random.randint(jax.random.key(seed), (2, PROMPT_LEN), 0,
+                                  cfg.vocab_size)
+        c0, logits = api.prefill(params, {"tokens": toks}, NULL_CTX)
+        cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+        args = (cur, jnp.full((2,), PROMPT_LEN, jnp.int32),
+                jnp.array([True, True]), jnp.full((2,), T, jnp.int32))
+        _, toks_free, _, _, _, _, _ = blk(params, c0, *args,
+                                          jnp.full((2,), -1, jnp.int32))
+        if _first_new_at(np.asarray(toks_free)[:, 0].tolist(), 3):
+            break
+    else:
+        pytest.fail("no prompt seed gives a new token at micro-step 3")
     stop = int(np.asarray(toks_free)[3, 0])
     c1, _ = api.prefill(params, {"tokens": toks}, NULL_CTX)
     _, toks_eos, emitted, _, _, act_o, _ = blk(
@@ -242,14 +263,14 @@ def test_engine_block_tokens_equal_per_step_engine(dense):
 
 
 @pytest.mark.parametrize("a_shards", [1, 2])
-def test_block_programs_compile_once_across_admissions(dense, a_shards):
+def test_block_programs_compile_once_across_admissions(dense_f32, a_shards):
     """Zero retracing (§4.3 invariant) extends to the macro-step regime:
     prefill1, admit, and EVERY decode-block bucket compile exactly once
     while calls grow across staggered admissions. Split-KV decode
     (a_shards > 1) keeps the SAME program names and the same bucket set —
     the shard count is a build-time static baked into each program, so the
     invariant (and this assertion set) cannot drift with the width."""
-    cfg, api, params = dense
+    cfg, api, params = dense_f32
     rt = StaticRuntime()
     eng = ServingEngine(api, NULL_CTX, 2, PROMPT_LEN, runtime=rt,
                         mode="continuous", max_new_cap=32, block_size=4,
@@ -267,13 +288,14 @@ def test_block_programs_compile_once_across_admissions(dense, a_shards):
                if n.startswith("serve_decode_block")) == stats["macro_steps"]
 
 
-def test_block_programs_compile_once_across_shard_resident_lengths(dense):
+def test_block_programs_compile_once_across_shard_resident_lengths(
+        dense_f32):
     """Cursor positions that land inside different shard blocks (shard 0
     only, mid-shard 1, the full extent) must all route through the SAME
     per-bucket programs — shard-resident length is traced state, never a
     compile key. Two runs with different length mixes: still one compile
     per program."""
-    cfg, api, params = dense
+    cfg, api, params = dense_f32
     rt = StaticRuntime()
     eng = ServingEngine(api, NULL_CTX, 2, PROMPT_LEN, runtime=rt,
                         mode="continuous", max_new_cap=32, block_size=4,
@@ -378,11 +400,17 @@ def test_ssm_family_serves_in_block_mode(ssm):
 
 def test_engine_eos_request_halts_early(dense):
     cfg, api, params = dense
-    probe = _requests(cfg, [(9, 0)])
-    ServingEngine(api, NULL_CTX, 2, PROMPT_LEN, mode="continuous",
-                  max_new_cap=32).run(params, probe, max_steps=100)
+    eng = ServingEngine(api, NULL_CTX, 2, PROMPT_LEN, mode="continuous",
+                        max_new_cap=32)
+    for seed in range(32):             # a prompt whose 4th token is new
+        probe = _requests(cfg, [(9, 0)], seed=seed)
+        eng.run(params, probe, max_steps=100)
+        if _first_new_at(probe[0].generated, 3):
+            break
+    else:
+        pytest.fail("no prompt seed gives a new token at index 3")
     stop = probe[0].generated[3]
-    reqs = _requests(cfg, [(9, 0)])
+    reqs = _requests(cfg, [(9, 0)], seed=seed)
     reqs[0].eos_id = stop
     ServingEngine(api, NULL_CTX, 2, PROMPT_LEN, mode="continuous",
                   max_new_cap=32, block_size=4).run(params, reqs,

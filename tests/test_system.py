@@ -1,5 +1,11 @@
 """End-to-end behaviour tests for the paper's system."""
 
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -38,6 +44,51 @@ def test_serve_driver_end_to_end():
                   max_new=4)
     assert stats["completed"] == 4
     assert stats["throughput_tok_s"] > 0
+
+
+def _run_fresh(code: str, **env_over) -> str:
+    """``code`` in a fresh interpreter (the compile-cache settings are
+    process-wide, so they are probed outside this test process)."""
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=str(root / "src"), JAX_PLATFORMS="cpu", **env_over)
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                         capture_output=True, text=True, env=env, timeout=120)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return out.stdout
+
+
+def test_compile_cache_uses_environment_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, compiled programs are cached
+    there and the helper sets no directory of its own."""
+    cache = tmp_path / "cache"
+    out = _run_fresh("""
+        import os, jax, jax.numpy as jnp
+        from repro.launch.serve import enable_compile_cache
+        d = enable_compile_cache()
+        assert d == os.environ["JAX_COMPILATION_CACHE_DIR"], d
+        assert jax.config.jax_compilation_cache_dir == d
+        jax.jit(lambda x: x * 2 + 1).lower(jnp.ones(8)).compile()
+        print(len(os.listdir(d)))
+        """, JAX_COMPILATION_CACHE_DIR=str(cache),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    assert int(out.split()[-1]) > 0
+
+
+def test_compile_cache_defaults_to_fixed_repo_dir():
+    """Without the variable, the cache sits at <repo root>/.jax_cache: a
+    fixed path (part of each entry's key), ignored by git."""
+    root = Path(__file__).resolve().parents[1]
+    out = _run_fresh("""
+        import jax
+        from repro.launch.serve import enable_compile_cache
+        d = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == d
+        print(d)
+        """)
+    assert out.split()[-1] == str(root / ".jax_cache")
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 def test_greedy_decode_is_deterministic():

@@ -1,0 +1,101 @@
+"""Compile for a described TPU v5e, without a chip.
+
+The TPU compiler is installed with JAX, so programs lower and compile for a
+chip that is described but not attached. This catches what interpret mode
+and the CPU backend cannot: Mosaic's tiling rules for a Pallas kernel
+(the last two block dims divisible by 8 and 128, or equal to the array's)
+and whether a full-width step program fits one chip.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and under several test workers
+only the worker given this file may try.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
+from repro.models import NULL_CTX, build_model
+
+# qwen2-0.5b decode widths: 14 query heads over 2 KV heads (G=7), hd 64
+B, N_KV, G, HD, S, BLOCK_S = 8, 2, 7, 64, 4096, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # can never be read back without one; keep the cache off meanwhile
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("partial", [False, True], ids=["full", "partial"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_flash_decode_compiles_for_v5e(one_chip, kv_dtype, partial):
+    quant = kv_dtype == "int8"
+    q = _spec((B, N_KV * G, HD), jnp.bfloat16, one_chip)
+    kv = _spec((B, N_KV, S, HD), jnp.dtype(kv_dtype), one_chip)
+    sc = _spec((B, N_KV, S, 1), jnp.float32, one_chip) if quant else None
+    mask = _spec((B, S), jnp.bool_, one_chip)
+    lim = _spec((1, 1), jnp.int32, one_chip)
+
+    def call(q, k, v, ks, vs, mask, lim):
+        return flash_decode_pallas(q, k, v, ks, vs, mask, block_s=BLOCK_S,
+                                   kv_limit=lim, partial_stats=partial)
+
+    compiled = jax.jit(call).lower(q, kv, kv, sc, sc, mask, lim).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_decode_block_compiles_for_v5e(one_chip):
+    """The serving engine's macro-step program (T=8, 8 slots, KV extent
+    256) at the full published qwen2-0.5b widths, from eval_shape shapes."""
+    cfg = get_config("qwen2-0.5b")
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab_size) == (24, 896, 151936)
+    api = build_model(cfg)
+    slots, extent, T = 8, 256, 8
+
+    def place(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = place(jax.eval_shape(api.init, jax.random.key(0)))
+    caches = place(jax.eval_shape(lambda: api.init_caches(slots, extent)))
+    i32 = _spec((slots,), jnp.int32, one_chip)
+    act = _spec((slots,), jnp.bool_, one_chip)
+
+    def block(p, c, tok, pos, act, rem, eos):
+        return api.decode_block(p, c, tok, pos, act, rem, eos, NULL_CTX,
+                                block_size=T)
+
+    compiled = jax.jit(block, donate_argnums=(1,)).lower(
+        params, caches, i32, i32, act, i32, i32).compile()
+    param_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in jax.tree.leaves(params))
+    assert param_bytes == 988_065_536
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= param_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
