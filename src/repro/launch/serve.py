@@ -187,7 +187,33 @@ def main(argv=None):
     rt = stats.pop("runtime")
     rejected = stats.pop("rejected")
     tiered = stats.pop("tiered", None)
+    spans = stats.pop("spans")
+    boundary = stats.pop("boundary")
+    kv = stats.pop("kv", None)
     print("serve stats:", stats)
+    # where a boundary's time goes (runtime/spans.py): nested spans
+    # (swap_* in policies/admit, prefill in admit) count in both rows
+    print("phases (serve:<phase> spans; dispatch and boundary in memory "
+          "only):")
+    for name, sp in spans.items():
+        print(f"  {name:16s} n={sp['n']:6d} p50={sp['p50_ms']:9.3f}ms "
+              f"p90={sp['p90_ms']:9.3f}ms total={sp['total_ms']:10.2f}ms")
+    print(f"  host per decode boundary: n={boundary['n']} "
+          f"p50={boundary['host_p50_ms']:.3f}ms "
+          f"p90={boundary['host_p90_ms']:.3f}ms")
+    if per_req:
+        split = {k: float(np.median([m[k] for m in per_req]))
+                 for k in ("queue_delay_ms", "lane_wait_ms", "prefill_ms",
+                           "ttft_ms")}
+        print(f"ttft split (p50): queue={split['queue_delay_ms']:.1f}ms "
+              f"lane_wait={split['lane_wait_ms']:.1f}ms "
+              f"prefill={split['prefill_ms']:.1f}ms "
+              f"(ttft p50 {split['ttft_ms']:.1f}ms)")
+    if kv:
+        print(f"kv: in use {100 * kv['in_use_share_mean']:.1f}% of "
+              f"{kv['reserved_tokens']} reserved positions (mean over "
+              f"decode dispatches); chunk lane depth mean "
+              f"{kv['lane_depth_mean']:.2f}")
     if tiered:
         # host-side placement arbiter view (KVArbiter): tier occupancy,
         # in-program demotions counted off cursor watermarks, byte savings

@@ -46,12 +46,16 @@ drain-then-refill loop is kept as ``mode="drain"`` — the baseline the
 continuous scheduler is measured against, and the fallback for model
 families without slotted support.
 
-Per-request accounting: queue delay (enqueue→admit), TTFT (enqueue→first
-token, spanning chunk boundaries under chunked admission), TPOT, and max
+Per-request accounting: queue delay (enqueue→admit), lane wait (admit→first
+prefill dispatch), prefill (first prefill dispatch→first token), TTFT (their
+sum, spanning chunk boundaries under chunked admission), TPOT, and max
 inter-token gap (the decode-stall a prefill inflicts on in-flight requests).
-Engine-level: decode-token throughput over decode wall-time only — prefill
-AND chunk-prefill wall-time are excluded from both sides — host syncs per
-decode token, and per-macro-step token counts.
+Engine-level: every time comes from one span table per run
+(``runtime/spans.py``: a ``serve:<phase>`` span per boundary phase, counters
+at each decode dispatch) — decode-token throughput over decode wall-time
+only (prefill AND chunk-prefill wall-time are excluded from both sides),
+host time per decode boundary, KV positions in use against reserved, host
+syncs per decode token, and per-macro-step token counts.
 
 **Serving under pressure** (DESIGN.md §7, failure model): requests carry a
 ``priority`` lane and TTFT/TPOT deadline fields; admission drains the queue
@@ -91,6 +95,7 @@ from repro.models.common import dtype_of
 from repro.models.param_specs import cache_specs, param_specs
 from repro.models.registry import DECODE_SLACK, ModelAPI
 from repro.models.sharding import ShardingCtx
+from repro.runtime.spans import SpanTable
 from repro.runtime.static_runtime import DispatchError, StaticRuntime
 
 
@@ -159,6 +164,7 @@ class Request:
     generated: List[int] = field(default_factory=list)
     t_enqueue: float = 0.0
     t_admitted: float = 0.0
+    t_first_chunk: float = 0.0          # start of its first prefill dispatch
     t_first_token: float = 0.0
     t_done: float = 0.0
     admit_step: int = -1                # decode step at which it got a slot
@@ -193,6 +199,10 @@ class Request:
         self.t_last_emit = now
 
     def metrics(self) -> Dict[str, Any]:
+        """Per-request latencies in ms. TTFT splits into queue delay
+        (enqueue→admission), lane wait (admission→first prefill dispatch,
+        the wait for the chunk lane) and prefill (first prefill
+        dispatch→first token): the three sum to ``ttft_ms``."""
         n = len(self.generated)
         ttft = max(0.0, self.t_first_token - self.t_enqueue) * 1e3
         tpot = ((self.t_done - self.t_first_token) / (n - 1) * 1e3
@@ -204,6 +214,10 @@ class Request:
             "arrival_step": self.arrival_step,
             "admit_step": self.admit_step,
             "queue_delay_ms": max(0.0, self.t_admitted - self.t_enqueue) * 1e3,
+            "lane_wait_ms": max(0.0, self.t_first_chunk - self.t_admitted)
+            * 1e3,
+            "prefill_ms": max(0.0, self.t_first_token - self.t_first_chunk)
+            * 1e3,
             "ttft_ms": ttft,
             "tpot_ms": tpot,
             "max_gap_ms": self.max_gap * 1e3,
@@ -325,6 +339,7 @@ class SlotScheduler:
         """Admit a fresh request into a free slot (PREFILL phase); its
         chunks run one per boundary from the admission FIFO."""
         r.t_admitted = time.monotonic()
+        r.t_first_chunk = 0.0
         r.admit_step = step
         r.status = "active"
         self.req[slot] = r
@@ -1495,11 +1510,11 @@ class ServingEngine:
         (stats would blend workloads), and the executor's caches from a
         finished run must never seed the next one (stale KV in freed
         slots)."""
-        self.tpot_samples: List[float] = []
+        # every engine timing: spans per boundary phase, counters per
+        # decode dispatch (runtime/spans.py)
+        self.spans = SpanTable()
         self.host_syncs = 0
         self._decode_tokens = 0
-        self._decode_time = 0.0
-        self._prefill_time = 0.0
         self._prefill_chunks = 0
         self._block_tokens: List[int] = []
         self._macro_steps = 0
@@ -1514,7 +1529,6 @@ class ServingEngine:
         self._restores = 0
         self._retries = 0
         self._watchdog_timeouts = 0
-        self._swap_time = 0.0
         self._quarantined: set = set()
         # emission log: (rid, token_index) in host-visible order — the
         # chaos invariant checker proves no token was duplicated, lost or
@@ -1558,12 +1572,13 @@ class ServingEngine:
         dispatch exceeding ``watchdog_s`` wall-clock bumps the watchdog
         counter (the work DID run — JAX cannot cancel an in-flight
         dispatch — so the watchdog detects and records stalls rather than
-        aborting them)."""
+        aborting them). Each attempt that returns is the in-memory span
+        ``dispatch``, which the watchdog reads."""
         attempt = 0
         while True:
-            t0 = time.monotonic()
             try:
-                out = fn(*args)
+                with self.spans.span("dispatch", emit=False) as sp:
+                    out = fn(*args)
             except DispatchError as e:
                 if attempt >= self.max_retries:
                     raise DispatchFailure(name, attempt + 1, e) from e
@@ -1572,7 +1587,7 @@ class ServingEngine:
                 if self.retry_backoff_s:
                     time.sleep(self.retry_backoff_s * (2 ** (attempt - 1)))
                 continue
-            if self.watchdog_s and time.monotonic() - t0 > self.watchdog_s:
+            if self.watchdog_s and sp.seconds > self.watchdog_s:
                 self._watchdog_timeouts += 1
             return out
 
@@ -1691,31 +1706,35 @@ class ServingEngine:
         done: List[Request] = []
         steps = admissions = overlapped = 0
         s_max = self.prompt_len + self.max_new_cap
+        spans = self.spans
         while sched.work_remaining():
             if steps >= max_steps:
                 break
-            sched.pump(steps)
-            if sched.usable_capacity() == 0:
-                # every slot quarantined: nothing can ever be admitted
-                # again — demote ALL remaining work to structured
-                # rejections instead of spinning to max_steps
-                for r in sched.pending + sched.queue:
-                    self._reject(r, "no usable slots (all quarantined)")
-                sched.pending.clear()
-                sched.queue.clear()
-                break
-            self._shed_deadlines(sched)
-            self._bound_queue(sched)
-            self._apply_pressure(sched, steps)
-            self._apply_kv_budget(sched)
-            self._priority_preempt(sched)
+            spans.open_boundary()
+            with spans.span("policies"):
+                sched.pump(steps)
+                if sched.usable_capacity() == 0:
+                    # every slot quarantined: nothing can ever be admitted
+                    # again — demote ALL remaining work to structured
+                    # rejections instead of spinning to max_steps
+                    for r in sched.pending + sched.queue:
+                        self._reject(r, "no usable slots (all quarantined)")
+                    sched.pending.clear()
+                    sched.queue.clear()
+                    break
+                self._shed_deadlines(sched)
+                self._bound_queue(sched)
+                self._apply_pressure(sched, steps)
+                self._apply_kv_budget(sched)
+                self._priority_preempt(sched)
             # "overlapped" = admitted while the batch was already live at
             # the start of this boundary (cold-start fills don't count)
             batch_live = sched.occupied()
             if self.prefill_chunk:
                 while True:
-                    n_adm, n_ovl, fin = self._admission_phase(
-                        params, sched, steps, batch_live)
+                    with spans.span("admit"):
+                        n_adm, n_ovl, fin = self._admission_phase(
+                            params, sched, steps, batch_live)
                     admissions += n_adm
                     overlapped += n_ovl
                     done.extend(fin)
@@ -1727,8 +1746,9 @@ class ServingEngine:
                     if sched.decode_active().any() or not sched.prefill_fifo:
                         break
             else:
-                n_adm, n_ovl, fin = self._admission_phase(
-                    params, sched, steps, batch_live)
+                with spans.span("admit"):
+                    n_adm, n_ovl, fin = self._admission_phase(
+                        params, sched, steps, batch_live)
                 admissions += n_adm
                 overlapped += n_ovl
                 done.extend(fin)
@@ -1741,6 +1761,7 @@ class ServingEngine:
                 continue
             done.extend(self._decode_round(params, sched, active, s_max))
             self._observe_tiers(sched)
+            spans.close_boundary()
             steps += T
         self._caches = ex.caches
         return self._stats(done, steps, admissions, overlapped)
@@ -1863,14 +1884,14 @@ class ServingEngine:
         slot and requeue. False if the swap-out dispatch failed."""
         ex = self._ex
         r = sched.req[slot]
-        t0 = time.monotonic()
         try:
-            saved = self._dispatch(ex.program_prefix + "swap_out",
-                                   ex.swap_out, slot)
+            with self.spans.span("swap_out", rid=r.rid, slot=slot):
+                saved = self._dispatch(ex.program_prefix + "swap_out",
+                                       ex.swap_out, slot)
+                saved = tuple(None if a is None else np.asarray(a)
+                              for a in saved)
         except DispatchFailure:
             return False                 # victim keeps its slot
-        saved = tuple(None if a is None else np.asarray(a) for a in saved)
-        self._swap_time += time.monotonic() - t0
         r.swap = SwapState(saved=saved,
                            kv_len=int(sched.positions[slot]),
                            last_tok=int(sched.last_tok[slot]),
@@ -1889,17 +1910,16 @@ class ServingEngine:
         byte-identical to never having been preempted."""
         ex = self._ex
         st = r.swap
-        t0 = time.monotonic()
         try:
-            self._dispatch(ex.program_prefix + "swap_in", ex.swap_in,
-                           st.saved, slot, st.kv_len)
+            with self.spans.span("swap_in", rid=r.rid, slot=slot):
+                self._dispatch(ex.program_prefix + "swap_in", ex.swap_in,
+                               st.saved, slot, st.kv_len)
         except DispatchFailure as e:
             # the restore never touched the device (DispatchError fires
             # pre-call): the slot stays clean and FREE; the request is
             # demoted to a structured rejection
             self._reject(r, f"dispatch_failed:{e.name}")
             return False
-        self._swap_time += time.monotonic() - t0
         r.swap = None
         sched.resume_decode(slot, r, st)
         if self._arbiter is not None:
@@ -1956,17 +1976,17 @@ class ServingEngine:
         r.admit_step = steps
         r.status = "active"
         sched.req[slot] = r
-        t0 = time.monotonic()
         try:
-            first = self._dispatch(
-                ex.program_prefix + "admit", ex.admit_full, params,
-                pad_row(r.prompt, self.prompt_len), slot)
+            with self.spans.span("prefill", rid=r.rid, slot=slot) as sp:
+                r.t_first_chunk = sp.t0
+                first = self._dispatch(
+                    ex.program_prefix + "admit", ex.admit_full, params,
+                    pad_row(r.prompt, self.prompt_len), slot)
+                first.block_until_ready()
         except DispatchFailure as e:
             self._demote_admission(sched, slot, r, e)
             return []
-        first.block_until_ready()
         now = time.monotonic()
-        self._prefill_time += now - t0
         r.t_first_token = now
         r.note_emit(now)
         self._emit_token(r, np.asarray(first)[0])
@@ -2034,19 +2054,22 @@ class ServingEngine:
             return []
         slot, r, start, n_valid = job
         row = pad_row(r.prompt[start:start + n_valid], self.prefill_chunk)
-        t0 = time.monotonic()
         try:
-            tok = self._dispatch(ex.program_prefix + "prefill_chunk",
-                                 ex.run_chunk, params, row, slot, start,
-                                 n_valid)
+            with self.spans.span("chunk_dispatch", rid=r.rid,
+                                 slot=slot) as sp:
+                if not r.t_first_chunk:
+                    r.t_first_chunk = sp.t0
+                tok = self._dispatch(ex.program_prefix + "prefill_chunk",
+                                     ex.run_chunk, params, row, slot, start,
+                                     n_valid)
         except DispatchFailure as e:
             # the slot may hold a partially-written prompt — demote the
             # request, quarantine the slot (drops it from the FIFO too)
             self._demote_admission(sched, slot, r, e)
             return []
-        first = np.asarray(tok)                   # blocks: chunk wall-time
+        with self.spans.span("chunk_wait", rid=r.rid, slot=slot):
+            first = np.asarray(tok)               # blocks: chunk wall-time
         now = time.monotonic()
-        self._prefill_time += now - t0
         self._prefill_chunks += 1
         finished: List[Request] = []
         if sched.chunk_done(slot, start, n_valid):
@@ -2086,7 +2109,9 @@ class ServingEngine:
         structured rejection, never a hung engine."""
         T = self.block_size
         ex = self._ex
+        spans = self.spans
         finished: List[Request] = []
+        self._sample_occupancy(sched)
         if ex.overlap > 1:
             # scheduler-view micro-batch occupancy (single source of truth
             # with the layer loop's row split: micro_batch_slices) — a
@@ -2097,39 +2122,21 @@ class ServingEngine:
                 self._micro_batches_live += bool(act.any())
         if T == 1:
             while True:
-                t0 = time.monotonic()
                 try:
-                    nxt, new_pos = self._dispatch(
-                        ex.program_prefix + "decode", ex.decode_step,
-                        params, sched.last_tok, sched.positions, active)
+                    with spans.span("decode_dispatch"):
+                        out = self._dispatch(
+                            ex.program_prefix + "decode", ex.decode_step,
+                            params, sched.last_tok, sched.positions, active)
                 except DispatchFailure as e:
                     active = self._demote_decode(sched, finished, e)
                     if not active.any():
                         return finished
                     continue
                 break
-            nxt, new_pos = self._host_sync(nxt, new_pos)
-            dt = time.monotonic() - t0
-            self.tpot_samples.append(dt)
-            self._decode_time += dt
-            n_tok = int(active.sum())
-            sched.positions = new_pos.copy()
-            sched.last_tok = nxt.copy()
-            now = time.monotonic()
-            for i, r in enumerate(sched.req):
-                if r is None or sched.phase[i] != sched.DECODE:
-                    continue
-                self._emit_token(r, nxt[i])
-                # host-side budget mirror (the device manages it only in
-                # block mode) — keeps SwapState and the invariant checker
-                # uniform across T
-                sched.remaining[i] -= 1
-                r.note_emit(now)
-                if r.done:
-                    self._finish(r, now)
-                    finished.append(r)
-                    sched.retire(i)              # freed → next boundary
-                    self._safe_reset(sched, i)
+            with spans.span("decode_wait"):
+                out = self._host_sync(*out)
+            with spans.span("unpack"):
+                n_tok = self._unpack_step(sched, active, finished, *out)
         else:
             while True:
                 # length-aware bucket: smallest compiled extent covering
@@ -2140,47 +2147,91 @@ class ServingEngine:
                     sb = bucket_for(min(needed, s_max), ex.buckets)
                 else:
                     sb = ex.buckets[0]
-                t0 = time.monotonic()
                 try:
-                    out = self._dispatch(
-                        ex.program_prefix + "decode_block", ex.decode_block,
-                        params, sb, sched.last_tok, sched.positions, active,
-                        sched.remaining, sched.eos)
+                    with spans.span("decode_dispatch"):
+                        out = self._dispatch(
+                            ex.program_prefix + "decode_block",
+                            ex.decode_block, params, sb, sched.last_tok,
+                            sched.positions, active, sched.remaining,
+                            sched.eos)
                 except DispatchFailure as e:
                     active = self._demote_decode(sched, finished, e)
                     if not active.any():
                         return finished
                     continue
                 break
-            toks, emitted, last_d, pos_d, act_np, rem_d =\
-                self._host_sync(*out)
-            dt = time.monotonic() - t0
-            self.tpot_samples.append(dt / T)
-            self._decode_time += dt
-            sched.last_tok = last_d.copy()
-            sched.positions = pos_d.copy()
-            sched.remaining = rem_d.copy()
-            n_tok = int(emitted.sum())
-            now = time.monotonic()
-            for i, r in enumerate(sched.req):
-                if r is None or sched.phase[i] != sched.DECODE:
-                    continue
-                emitted_any = False
-                for t in range(T):
-                    if emitted[t, i]:
-                        self._emit_token(r, toks[t, i])
-                        emitted_any = True
-                if emitted_any:
-                    r.note_emit(now)
-                if not act_np[i]:                # budget/EOS halt on device
-                    self._finish(r, now)
-                    finished.append(r)
-                    sched.retire(i)              # freed → next boundary
-                    self._safe_reset(sched, i)
+            with spans.span("decode_wait"):
+                out = self._host_sync(*out)
+            with spans.span("unpack"):
+                n_tok = self._unpack_block(sched, finished, *out)
         self._decode_tokens += n_tok
         self._block_tokens.append(n_tok)
         self._macro_steps += 1
         return finished
+
+    def _unpack_step(self, sched: SlotScheduler, active, finished,
+                     nxt, new_pos) -> int:
+        """Emit one synced slotted step's tokens, retire what finished;
+        returns the tokens decoded."""
+        sched.positions = new_pos.copy()
+        sched.last_tok = nxt.copy()
+        now = time.monotonic()
+        for i, r in enumerate(sched.req):
+            if r is None or sched.phase[i] != sched.DECODE:
+                continue
+            self._emit_token(r, nxt[i])
+            # host-side budget mirror (the device manages it only in
+            # block mode) — keeps SwapState and the invariant checker
+            # uniform across T
+            sched.remaining[i] -= 1
+            r.note_emit(now)
+            if r.done:
+                self._finish(r, now)
+                finished.append(r)
+                sched.retire(i)              # freed → next boundary
+                self._safe_reset(sched, i)
+        return int(active.sum())
+
+    def _unpack_block(self, sched: SlotScheduler, finished, toks, emitted,
+                      last_d, pos_d, act_np, rem_d) -> int:
+        """Emit one synced block's tokens, retire the slots the device
+        halted; returns the tokens decoded."""
+        T = self.block_size
+        sched.last_tok = last_d.copy()
+        sched.positions = pos_d.copy()
+        sched.remaining = rem_d.copy()
+        now = time.monotonic()
+        for i, r in enumerate(sched.req):
+            if r is None or sched.phase[i] != sched.DECODE:
+                continue
+            emitted_any = False
+            for t in range(T):
+                if emitted[t, i]:
+                    self._emit_token(r, toks[t, i])
+                    emitted_any = True
+            if emitted_any:
+                r.note_emit(now)
+            if not act_np[i]:                # budget/EOS halt on device
+                self._finish(r, now)
+                finished.append(r)
+                sched.retire(i)              # freed → next boundary
+                self._safe_reset(sched, i)
+        return int(emitted.sum())
+
+    def _sample_occupancy(self, sched: SlotScheduler):
+        """Counters at a decode dispatch: the chunk lane's depth and, for
+        KV-cache families, the KV positions written in occupied slots (a
+        PREFILL slot its prompt tokens so far, a DECODE slot its cursor)."""
+        self.spans.sample("lane_depth", len(sched.prefill_fifo))
+        if self._kv_extent is None:
+            return
+        used = 0
+        for i, ph in enumerate(sched.phase):
+            if ph == sched.PREFILL:
+                used += sched.filled[i]
+            elif ph == sched.DECODE:
+                used += int(sched.positions[i])
+        self.spans.sample("kv_in_use", used)
 
     # ------------------------------------------------------------------
     def _run_drain(self, params, requests, max_steps):
@@ -2194,9 +2245,11 @@ class ServingEngine:
         last = None
         done: List[Request] = []
         steps = admissions = 0
+        spans = self.spans
         while pending or self.queue or any(r is not None for r in active_req):
             if steps >= max_steps:
                 break
+            spans.open_boundary()
             while pending and pending[0].arrival_step <= steps:
                 r = pending.pop(0)            # validated by run()
                 if not r.t_enqueue:           # keep a pre-run submit() stamp
@@ -2204,65 +2257,76 @@ class ServingEngine:
                 self.queue.append(r)
             if caches is None:
                 toks = np.zeros((self.slots, self.prompt_len), np.int32)
-                for i in range(self.slots):
-                    if active_req[i] is None and self.queue:
-                        r = self.queue.pop(0)
-                        r.t_admitted = time.monotonic()
-                        r.admit_step = steps
-                        active_req[i] = r
-                        admissions += 1
-                    if active_req[i] is not None:
-                        toks[i] = pad_row(active_req[i].prompt,
-                                          self.prompt_len)
+                with spans.span("admit"):
+                    for i in range(self.slots):
+                        if active_req[i] is None and self.queue:
+                            r = self.queue.pop(0)
+                            r.t_admitted = time.monotonic()
+                            r.admit_step = steps
+                            active_req[i] = r
+                            admissions += 1
+                        if active_req[i] is not None:
+                            toks[i] = pad_row(active_req[i].prompt,
+                                              self.prompt_len)
                 if not any(r is not None for r in active_req):
                     steps += 1                   # idle tick: await arrivals
                     continue
-                t0 = time.monotonic()
-                caches, first = ex.drain_prefill(params, toks)
-                first.block_until_ready()
+                with spans.span("prefill") as sp:
+                    caches, first = ex.drain_prefill(params, toks)
+                    first.block_until_ready()
                 now = time.monotonic()
-                self._prefill_time += now - t0
                 first = np.asarray(first)
                 for i, r in enumerate(active_req):
                     if r is not None and not r.generated:
+                        r.t_first_chunk = sp.t0
                         r.t_first_token = now
                         r.note_emit(now)
                         self._emit_token(r, first[i])
                         if r.done:
                             self._finish(r, now)
                 last = jnp.asarray(first.astype(np.int32))
-            t0 = time.monotonic()
-            caches, nxt = ex.drain_decode(params, caches, last)
-            nxt_np = self._host_sync(nxt)
-            dt = time.monotonic() - t0
-            self.tpot_samples.append(dt)
-            self._decode_time += dt
+            with spans.span("decode_dispatch"):
+                caches, nxt = ex.drain_decode(params, caches, last)
+            with spans.span("decode_wait"):
+                nxt_np = self._host_sync(nxt)
             self._macro_steps += 1
             last = nxt
             steps += 1
-            now = time.monotonic()
-            n_tok = 0
-            for i, r in enumerate(active_req):
-                if r is None or r.done:
-                    continue
-                self._emit_token(r, nxt_np[i])
-                r.note_emit(now)
-                n_tok += 1
-                if r.done:
-                    self._finish(r, now)
-            self._decode_tokens += n_tok
-            self._block_tokens.append(n_tok)
-            for i, r in enumerate(active_req):
-                if r is not None and r.done:
-                    done.append(r)
-                    active_req[i] = None
+            with spans.span("unpack"):
+                now = time.monotonic()
+                n_tok = 0
+                for i, r in enumerate(active_req):
+                    if r is None or r.done:
+                        continue
+                    self._emit_token(r, nxt_np[i])
+                    r.note_emit(now)
+                    n_tok += 1
+                    if r.done:
+                        self._finish(r, now)
+                self._decode_tokens += n_tok
+                self._block_tokens.append(n_tok)
+                for i, r in enumerate(active_req):
+                    if r is not None and r.done:
+                        done.append(r)
+                        active_req[i] = None
             if all(r is None for r in active_req):
                 caches = None                    # drained → allow re-prefill
+            spans.close_boundary()
         return self._stats(done, steps, admissions, 0)
 
     # ------------------------------------------------------------------
     def _stats(self, done, steps, admissions, overlapped) -> Dict[str, Any]:
-        tp = np.array(self.tpot_samples[1:] or [0.0])
+        """The run's counters and latencies. Every time here comes from
+        the span table: a decode round is its dispatch plus its sync (per
+        token: over the block size in the continuous scheduler), prefill
+        time is every chunk dispatch and sync plus monolithic and drain
+        prefill, swap time the swap-out and swap-in spans."""
+        spans = self.spans
+        per_tok = self.block_size if self.mode == "continuous" else 1
+        rounds = [(d + w) / per_tok for d, w in zip(
+            spans.seconds("decode_dispatch"), spans.seconds("decode_wait"))]
+        tp = np.array(rounds[1:] or [0.0])
+        decode_time = spans.total("decode_dispatch", "decode_wait")
         per_req = [r.metrics() for r in sorted(done, key=lambda r: r.rid)]
         ttfts = np.array([m["ttft_ms"] for m in per_req] or [0.0])
         qd = np.array([m["queue_delay_ms"] for m in per_req] or [0.0])
@@ -2294,8 +2358,9 @@ class ServingEngine:
             "queue_delay_mean_ms": float(qd.mean()),
             "max_inter_token_gap_ms": float(gaps.max()),
             "decode_tokens": n_dec,
-            "throughput_tok_s": float(n_dec / max(self._decode_time, 1e-9)),
-            "prefill_time_ms": float(self._prefill_time * 1e3),
+            "throughput_tok_s": float(n_dec / max(decode_time, 1e-9)),
+            "prefill_time_ms": float(spans.total(
+                "chunk_dispatch", "chunk_wait", "prefill") * 1e3),
             "prefill_chunks": self._prefill_chunks,
             "host_syncs": self.host_syncs,
             "syncs_per_token": float(self.host_syncs / max(n_dec, 1)),
@@ -2312,13 +2377,25 @@ class ServingEngine:
             "retries": self._retries,
             "watchdog_timeouts": self._watchdog_timeouts,
             "quarantined_slots": sorted(self._quarantined),
-            "swap_time_ms": float(self._swap_time * 1e3),
+            "swap_time_ms": float(spans.total("swap_out", "swap_in") * 1e3),
             "rejected": [
                 {"rid": r.rid, "status": r.status, "priority": r.priority,
                  "reason": r.reject_reason}
                 for r in sorted(self._rejected + self._deadline_missed,
                                 key=lambda r: r.rid)],
+            # per boundary phase: n and ms (nested spans count in both)
+            "spans": spans.summary(),
+            # host time per decode boundary: wall less the device waits
+            "boundary": spans.boundary_summary(),
         }
+        in_use = spans.mean("kv_in_use")
+        if in_use is not None:
+            # KV positions written in occupied slots, per decode dispatch,
+            # against every slot's full extent
+            reserved = self.slots * self._kv_extent
+            out["kv"] = {"reserved_tokens": reserved,
+                         "in_use_share_mean": in_use / reserved,
+                         "lane_depth_mean": spans.mean("lane_depth")}
         if self._arbiter is not None:
             # tiered-KV occupancy and placement policy: tier splits,
             # demotions counted off cursor watermarks, live/peak bytes and
@@ -2330,6 +2407,6 @@ class ServingEngine:
             # per-domain stall accounting of the overlap schedule
             out["wa"] = self._ex.routing_stats(n_dec)
             out["wa"].update(self._ex.overlap_stats(
-                self._decode_time, self._macro_steps,
+                decode_time, self._macro_steps,
                 self._micro_batches_live, self._micro_batches_total))
         return out

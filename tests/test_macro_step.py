@@ -343,7 +343,7 @@ def test_engine_reuse_starts_clean(dense):
     assert sb["completed"] == sa["completed"]
     assert sb["host_syncs"] == sa["host_syncs"]          # not accumulated
     assert sb["decode_tokens"] == sa["decode_tokens"]
-    assert len(eng.tpot_samples) == sa["macro_steps"]
+    assert eng.spans.count("decode_dispatch") == sa["macro_steps"]
     for a, b in zip(ra, rb):
         assert a.generated == b.generated                # fresh caches
 
