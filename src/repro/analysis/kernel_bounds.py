@@ -240,10 +240,22 @@ def check_chunk_writes(cell: Cell, rec: ProgramRecord, report: Report):
         report.warning(PASS, rec.name, "jaxpr",
                        f"could not retrace for chunk-write audit: {e}")
         return
-    leaves = [leaf for leaf in jax.tree_util.tree_leaves(caches)
-              if getattr(leaf, "ndim", 0) == 5]
-    slice_shapes = {leaf.shape[1:] for leaf in leaves}   # (B, n_kv, S, *)
-    stack_shapes = {leaf.shape for leaf in leaves}       # (L, B, n_kv, S, *)
+    stacks = {leaf.shape for leaf in jax.tree_util.tree_leaves(caches)
+              if getattr(leaf, "ndim", 0) == 5}          # (L, B, n_kv, S, *)
+
+    def slot_dim(shape):
+        """Slot axis of a per-layer slice (B, n_kv, S, *) or a stack
+        (L, B, n_kv, S, *) — whole, or one batch shard's inside a
+        shard_map — else None."""
+        for full in stacks:
+            if len(shape) == 5 and shape[0] == full[0] \
+                    and shape[2:] == full[2:] and full[1] % shape[1] == 0:
+                return 1
+            if len(shape) == 4 and shape[1:] == full[2:] \
+                    and full[1] % shape[0] == 0:
+                return 0
+        return None
+
     B = cell.spec.slots
     n_checked = 0
     for site in iter_eqns(jaxpr):
@@ -252,22 +264,19 @@ def check_chunk_writes(cell: Cell, rec: ProgramRecord, report: Report):
             continue
         dst, upd, *starts = eqn.invars
         dshape = tuple(dst.aval.shape)
-        if dshape in slice_shapes:                       # per-layer write
-            slot_dim = 0
-        elif dshape in stack_shapes:                     # whole-stack write
-            slot_dim = 1
-        else:
+        dim = slot_dim(dshape)
+        if dim is None:
             continue
         n_checked += 1
-        extent = upd.aval.shape[slot_dim]
-        start = literal_value(starts[slot_dim])
+        extent = upd.aval.shape[dim]
+        start = literal_value(starts[dim])
         if extent == 1:
             continue
-        if extent == dshape[slot_dim] and start == 0:
+        if extent == dshape[dim] and start == 0:
             continue                                     # full-width literal
         report.error(
             PASS, rec.name,
-            f"dynamic_update_slice dst {dshape} slot dim {slot_dim}",
+            f"dynamic_update_slice dst {dshape} slot dim {dim}",
             f"chunk write updates {extent} slots at "
             f"{'a TRACED offset' if start is None else f'offset {start}'} "
             "— a masked chunk/shard write may alias a neighbouring "

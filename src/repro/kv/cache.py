@@ -245,10 +245,12 @@ def read_kv(cache: KVCache, layer: jax.Array, dtype=jnp.bfloat16):
 
 
 # ---------------------------------------------------------------------------
-# Per-layer slice API — used inside decode layer-scans so each layer touches
-# ONLY its own (B,n_kv,S,hd) slice (the whole-cache carry would cost O(L)
-# bytes per layer ⇒ O(L²) per step; slices flow as scan xs/ys instead and
-# alias in place under donation).
+# Per-layer API. Reads take one layer's (B,n_kv,S,hd) slice. The slotted
+# writes below also take a write ADDRESS that may lead with a layer index:
+# the serving layer loop (models/transformer.py) carries the whole
+# (L,B,n_kv,S,hd) stacks and writes layer l's new tokens into them in place,
+# so a donated cache aliases from program entry to exit and no stack is ever
+# rebuilt or copied.
 # ---------------------------------------------------------------------------
 
 def layer_append(k_l: jax.Array, v_l: jax.Array, k_scale_l, v_scale_l,
@@ -350,58 +352,103 @@ def layer_read_shards(k_l, v_l, k_scale_l, v_scale_l, bucket: int,
 # (B,) vectors, so every program below compiles exactly once.
 # ---------------------------------------------------------------------------
 
+def _split_address(at):
+    """``(layer, index)`` → ((layer,), index): write into layer ``layer`` of
+    (L, B, …) stacks. A bare ``index`` → ((), index): write into a (B, …)
+    layer slice."""
+    if isinstance(at, tuple):
+        layer, at = at
+        return (layer,), at
+    return (), at
+
+
+def _write_rows(dst, new, slots, active, lead=()):
+    """Row b of ``dst`` (*lead, B, n_kv, S, d) takes ``new[b]`` (n_kv, d) at
+    position ``slots[b]``, where ``active[b]``; ``lead`` indexes the leading
+    (layer) axes. One dynamic_update_slice per row, of the tile-aligned
+    window of W positions that holds the slot: the window is read back and
+    changed at the slot alone, so an inactive row rewrites its own bytes and
+    the rest of the slice is never read or written. W is one (8,128) tile's
+    rows (8 for 4-byte types, 16 for bf16, 32 for int8): an unaligned
+    one-position write makes XLA's TPU layout assignment relayout the whole
+    carried stack at program entry and exit. Out-of-range slots clamp to
+    [0, S) as ``dynamic_update_slice`` clamps them."""
+    B, n_kv, d = new.shape
+    S = dst.shape[-2]
+    W = min(S, 8 * max(1, 4 // dst.dtype.itemsize))
+    offs = jnp.arange(W, dtype=jnp.int32)
+    new = new.astype(dst.dtype)
+    for b in range(B):
+        slot = jnp.clip(slots[b], 0, S - 1)
+        start = jnp.minimum(slot - jax.lax.rem(slot, W), S - W)
+        at = lead + (b, 0, start, 0)
+        old = jax.lax.dynamic_slice(dst, at,
+                                    (1,) * (len(lead) + 1) + (n_kv, W, d))
+        hit = ((start + offs == slot) & active[b])[:, None]       # (W,1)
+        dst = jax.lax.dynamic_update_slice(
+            dst, jnp.where(hit, new[b][:, None, :], old), at)
+    return dst
+
+
 def layer_append_slotted(k_l: jax.Array, v_l: jax.Array, k_scale_l, v_scale_l,
                          k_new: jax.Array, v_new: jax.Array,
-                         positions: jax.Array, window: int,
+                         positions, window: int,
                          active: Optional[jax.Array] = None):
     """Per-row append: row ``b`` writes ``k_new[b]`` at its OWN cursor
-    ``positions[b]`` (vmapped dynamic_update_slice — rows may sit at
-    different depths). k_l/v_l: (B,n_kv,S,hd); k_new/v_new: (B,n_kv,hd);
-    positions: (B,) int32; active: (B,) bool — inactive rows keep their
-    slice byte-identical (retired slots must not pollute the cache)."""
-    size = k_l.shape[2]
+    ``positions[b]`` (rows may sit at different depths). k_new/v_new:
+    (B,n_kv,hd); active: (B,) bool — an inactive row writes nothing
+    (retired slots must not pollute the cache). ``positions``: (B,) int32
+    cursors into (B,n_kv,S,hd) layer slices, or ``(layer, cursors)`` to
+    append to layer ``layer`` of (L,B,n_kv,S,hd) stacks in place. Each row
+    writes one tile-aligned window (``_write_rows``): the active mask
+    applies to the written token, never to the slice. Returns the updated
+    buffers; quantizes when scale buffers are present."""
+    lead, positions = _split_address(positions)
+    size = k_l.shape[-2]
     slots = jax.lax.rem(positions, size) if window else positions
     if active is None:
         active = jnp.ones(positions.shape, bool)
 
-    def row(dst, new, slot, act):
-        upd = jax.lax.dynamic_update_slice(
-            dst, new[:, None, :].astype(dst.dtype), (0, slot, 0))
-        return jnp.where(act, upd, dst)
+    def put(dst, new):
+        return _write_rows(dst, new, slots, active, lead)
 
     if k_scale_l is not None:
         kq, ks = quantize_kv(k_new)
         vq, vs = quantize_kv(v_new)
-        return (jax.vmap(row)(k_l, kq, slots, active),
-                jax.vmap(row)(v_l, vq, slots, active),
-                jax.vmap(row)(k_scale_l, ks, slots, active),
-                jax.vmap(row)(v_scale_l, vs, slots, active))
-    return (jax.vmap(row)(k_l, k_new, slots, active),
-            jax.vmap(row)(v_l, v_new, slots, active), None, None)
+        return put(k_l, kq), put(v_l, vq), put(k_scale_l, ks), \
+            put(v_scale_l, vs)
+    return put(k_l, k_new), put(v_l, v_new), None, None
+
+
+def _put_chunk(dst, new, at, keep):
+    """Write ``new`` (n_kv, C, d) at ``at`` = lead + (slot, 0, start, 0) of
+    ``dst``; chunk positions where ``keep`` is False keep their bytes."""
+    size = (1,) * (len(at) - 3) + new.shape
+    cur = jax.lax.dynamic_slice(dst, at, size).reshape(new.shape)
+    new = jnp.where(keep, new.astype(dst.dtype), cur)
+    return jax.lax.dynamic_update_slice(dst, new.reshape(size), at)
 
 
 def layer_write_chunk(k_l: jax.Array, v_l: jax.Array, k_scale_l, v_scale_l,
                       k_new: jax.Array, v_new: jax.Array, slot,
                       start, valid_len):
     """Chunked-prefill write: ONE slot's (C,)-wide chunk lands at cache
-    positions [start, start+C) of row ``slot``. k_l/v_l: (B,n_kv,S,hd);
-    k_new/v_new: (n_kv,C,hd); slot/start/valid_len are traced scalars — one
-    compiled program serves every chunk of every prompt. Chunk positions
-    >= ``valid_len`` (last-chunk padding) keep their previous bytes, so the
-    cache past a prompt's true length is never touched and per-row cursor
-    masks stay the single source of validity. Quantizes per position when
-    scale slices are present (int8 caches store the chunk pre-dequant)."""
+    positions [start, start+C) of row ``slot``. k_l/v_l: (B,n_kv,S,hd)
+    layer slices, or (L,B,n_kv,S,hd) stacks with ``slot`` given as
+    ``(layer, slot)``, written in place; k_new/v_new: (n_kv,C,hd);
+    slot/start/valid_len are traced scalars — one compiled program serves
+    every chunk of every prompt. Chunk positions >= ``valid_len``
+    (last-chunk padding) keep their previous bytes, so the cache past a
+    prompt's true length is never touched and per-row cursor masks stay the
+    single source of validity. Quantizes per position when scale buffers
+    are present (int8 caches store the chunk pre-dequant)."""
+    lead, slot = _split_address(slot)
+    at = lead + (slot, 0, start, 0)
     C = k_new.shape[1]
     keep = (jnp.arange(C, dtype=jnp.int32) < valid_len)[None, :, None]
 
     def put(dst, new):
-        if dst is None:
-            return None
-        cur = jax.lax.dynamic_slice(
-            dst, (slot, 0, start, 0), (1,) + new.shape)
-        new = jnp.where(keep, new.astype(dst.dtype), cur[0])
-        return jax.lax.dynamic_update_slice(dst, new[None],
-                                            (slot, 0, start, 0))
+        return None if dst is None else _put_chunk(dst, new, at, keep)
 
     if k_scale_l is not None:
         kq, ks = quantize_kv(k_new)
@@ -436,35 +483,37 @@ def layer_read_slot(k_l, v_l, k_scale_l, v_scale_l, slot,
 # the exact values of the most recent positions; "demotion" is the read-side
 # boundary ``cold_boundary(count)`` advancing by cold_block inside the
 # compiled program. Both writes are slot-extent-1 dynamic_update_slices, the
-# same isolation contract the kernel-bounds pass audits for flat caches.
+# same isolation contract the kernel-bounds pass audits for flat caches, and
+# take the same layer-leading write address as the flat writes.
 # ---------------------------------------------------------------------------
 
 def layer_append_tiered(k_l, v_l, k_scale_l, v_scale_l, hot_k_l, hot_v_l,
-                        k_new, v_new, positions: jax.Array,
-                        cold_dtype: str, active: Optional[jax.Array] = None):
+                        k_new, v_new, positions, cold_dtype: str,
+                        active: Optional[jax.Array] = None):
     """Decode append for a tiered layer: stage the new position into the
     cold tier (quantized at ``cold_dtype``) AND write it exactly into the
     hot ring at slot position % H. k_l/v_l: (B,n_kv,S,hd_c); hot rings
-    (B,n_kv,H,hd); k_new/v_new: (B,n_kv,hd); positions: (B,) int32."""
-    H = hot_k_l.shape[2]
+    (B,n_kv,H,hd); k_new/v_new: (B,n_kv,hd); positions: (B,) int32, or
+    ``(layer, positions)`` for (L,B,…) stacks written in place — the same
+    per-row window writes as ``layer_append_slotted``."""
+    lead, positions = _split_address(positions)
+    H = hot_k_l.shape[-2]
     ring = jax.lax.rem(positions, H)
     if active is None:
         active = jnp.ones(positions.shape, bool)
 
-    def row(dst, new, slot, act):
-        upd = jax.lax.dynamic_update_slice(
-            dst, new[:, None, :].astype(dst.dtype), (0, slot, 0))
-        return jnp.where(act, upd, dst)
+    def put(dst, new, slots):
+        return _write_rows(dst, new, slots, active, lead)
 
     kq, ks = quantize_cold(k_new, cold_dtype)
     vq, vs = quantize_cold(v_new, cold_dtype)
-    k_l = jax.vmap(row)(k_l, kq, positions, active)
-    v_l = jax.vmap(row)(v_l, vq, positions, active)
+    k_l = put(k_l, kq, positions)
+    v_l = put(v_l, vq, positions)
     if k_scale_l is not None:
-        k_scale_l = jax.vmap(row)(k_scale_l, ks, positions, active)
-        v_scale_l = jax.vmap(row)(v_scale_l, vs, positions, active)
-    hot_k_l = jax.vmap(row)(hot_k_l, k_new, ring, active)
-    hot_v_l = jax.vmap(row)(hot_v_l, v_new, ring, active)
+        k_scale_l = put(k_scale_l, ks, positions)
+        v_scale_l = put(v_scale_l, vs, positions)
+    hot_k_l = put(hot_k_l, k_new, ring)
+    hot_v_l = put(hot_v_l, v_new, ring)
     return k_l, v_l, k_scale_l, v_scale_l, hot_k_l, hot_v_l
 
 
@@ -520,25 +569,23 @@ def layer_write_chunk_tiered(k_l, v_l, k_scale_l, v_scale_l, hot_k_l,
     ``layer_write_chunk``'s keep-past-valid masking) and the hot ring takes
     a residue write — ring slot s receives the LAST valid chunk position
     ≡ s (mod H); ring slots the chunk does not cover keep their bytes (they
-    hold still-hot positions of earlier chunks). k_new/v_new: (n_kv,C,hd)."""
+    hold still-hot positions of earlier chunks). k_new/v_new: (n_kv,C,hd);
+    ``slot`` may be ``(layer, slot)`` for (L,B,…) stacks written in
+    place."""
+    lead, slot = _split_address(slot)
     C = k_new.shape[1]
     keep = (jnp.arange(C, dtype=jnp.int32) < valid_len)[None, :, None]
 
     def put(dst, new):
-        if dst is None:
-            return None
-        cur = jax.lax.dynamic_slice(
-            dst, (slot, 0, start, 0), (1,) + new.shape)
-        new = jnp.where(keep, new.astype(dst.dtype), cur[0])
-        return jax.lax.dynamic_update_slice(dst, new[None],
-                                            (slot, 0, start, 0))
+        return None if dst is None else \
+            _put_chunk(dst, new, lead + (slot, 0, start, 0), keep)
 
     kq, ks = quantize_cold(k_new, cold_dtype)
     vq, vs = quantize_cold(v_new, cold_dtype)
     k_l, v_l = put(k_l, kq), put(v_l, vq)
     k_scale_l, v_scale_l = put(k_scale_l, ks), put(v_scale_l, vs)
 
-    H = hot_k_l.shape[2]
+    H = hot_k_l.shape[-2]
     s_idx = jnp.arange(H, dtype=jnp.int32)
     # r = (ring slot − start) mod H: chunk index of the FIRST position that
     # lands in ring slot s; the last valid one is r + H·⌊(valid−1−r)/H⌋
@@ -547,10 +594,8 @@ def layer_write_chunk_tiered(k_l, v_l, k_scale_l, v_scale_l, hot_k_l,
     keep_h = (r < valid_len)[None, :, None]
 
     def put_hot(dst, new):
-        g = jnp.take(new, i_star, axis=1)                   # (n_kv,H,hd)
-        cur = jax.lax.dynamic_slice(dst, (slot, 0, 0, 0), (1,) + g.shape)
-        g = jnp.where(keep_h, g.astype(dst.dtype), cur[0])
-        return jax.lax.dynamic_update_slice(dst, g[None], (slot, 0, 0, 0))
+        return _put_chunk(dst, jnp.take(new, i_star, axis=1),  # (n_kv,H,hd)
+                          lead + (slot, 0, 0, 0), keep_h)
 
     return (k_l, v_l, k_scale_l, v_scale_l,
             put_hot(hot_k_l, k_new), put_hot(hot_v_l, v_new))

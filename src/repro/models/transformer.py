@@ -11,11 +11,11 @@ identical, the collective schedule is not.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kv.cache import KVCache, init_kv_cache
@@ -154,16 +154,83 @@ def block_decode(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
     return x, (k_l, v_l, ks_l, vs_l)
 
 
+_KV_LEAVES = ("k", "v", "k_scale", "v_scale", "hot_k", "hot_v")
+
+
+def _slice_dims(name: str) -> Tuple:
+    """Logical dims of one layer's slice of a cache leaf (``cache_specs``
+    without the layer dim); the hot ring's dim 2 is the ring, not kv_seq."""
+    if name.startswith("hot"):
+        return ("batch", "kv_heads", None, None)
+    return ("batch", "kv_heads", "kv_seq", None)
+
+
+def _layer_slices(cache: KVCache, layer, ctx: ShardingCtx) -> Tuple:
+    """Layer ``layer``'s slices of the (k, v, k_scale, v_scale, hot_k,
+    hot_v) stacks (``None`` where the cache has no such leaf), pinned to the
+    cache's layout: on a mesh the pin keeps a later per-slot slice from
+    folding into one slice of the whole stack, which GSPMD would gather."""
+    out = []
+    for name in _KV_LEAVES:
+        a = getattr(cache, name)
+        if a is not None:
+            a = ctx.ann(jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                     keepdims=False),
+                        *_slice_dims(name))
+        out.append(a)
+    return tuple(out)
+
+
+def _write_kv(ctx: ShardingCtx, cache: KVCache, write, rows: Tuple = (),
+              shared: Tuple = ()) -> KVCache:
+    """Apply ``write(cache, rows, shared, row0) -> cache`` to the KV stacks
+    in place. Where the mesh shards the batch, each batch shard runs
+    ``write`` on its own stack shard, its own ``rows`` (operands that lead
+    with the batch dim) and the replicated ``shared`` operands; ``row0`` is
+    the shard's first global row (0 off a mesh). A slot's write reads back
+    the window it changes, and GSPMD gathers the whole operand of a slice
+    taken along a sharded dim: here every layer of the stacks."""
+    names = tuple(n for n in _KV_LEAVES if getattr(cache, n) is not None)
+
+    def run(stacks, rows, shared, row0):
+        c = write(cache._replace(length=None, **dict(zip(names, stacks))),
+                  rows, shared, row0)
+        return tuple(getattr(c, n) for n in names)
+
+    stacks = tuple(getattr(cache, n) for n in names)
+    dp = ()
+    if ctx.mesh is not None and not ctx.mesh.empty:
+        ax = ctx.spec((None, "batch"), cache.k.shape[:2])[1]
+        dp = () if ax is None else (tuple(ax) if isinstance(ax, tuple)
+                                    else (ax,))
+    if not dp:
+        out = run(stacks, rows, shared, 0)
+    else:
+        from jax.sharding import PartitionSpec as P
+        ax = dp if len(dp) > 1 else dp[0]
+        n_rows = cache.k.shape[1] // int(
+            np.prod([ctx.mesh.shape[a] for a in dp]))
+        out = jax.shard_map(
+            lambda s, r, sh: run(s, r, sh, jax.lax.axis_index(dp) * n_rows),
+            mesh=ctx.mesh, in_specs=(P(None, ax), P(ax), P()),
+            out_specs=P(None, ax), axis_names=frozenset(dp),
+            check_vma=False)(stacks, rows, shared)
+    return cache._replace(**dict(zip(names, out)))
+
+
 def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
-                         ctx: ShardingCtx, kv_slices: Tuple,
+                         ctx: ShardingCtx, cache: KVCache, layer,
                          positions: jax.Array, active: jax.Array,
                          window: int = 0, kv_bucket: int = 0,
-                         kv_shards: int = 1) -> Tuple[jax.Array, Tuple]:
-    """``block_decode`` with PER-ROW cursors (continuous batching): row b
-    appends at its own ``positions[b]`` and attends over its own prefix.
-    Inactive rows write nothing (their KV slice stays byte-identical); their
-    activations still flow — static shapes — but the engine masks the
-    resulting logits.
+                         kv_shards: int = 1) -> Tuple[jax.Array, KVCache]:
+    """``block_decode`` with PER-ROW cursors (continuous batching) for layer
+    ``layer`` of the carried ``cache`` stacks: row b writes its new K/V
+    into the stacks in place at (layer, b, positions[b])
+    (``layer_append_slotted`` / ``layer_append_tiered``), then the layer's
+    slice is read back for attention over the row's own prefix. Inactive
+    rows write nothing (their KV stays byte-identical); their activations
+    still flow — static shapes — but the engine masks the resulting
+    logits. Returns (x', cache').
 
     ``kv_bucket`` > 0 (non-windowed caches only) reads and attends only the
     first ``kv_bucket`` cache positions — the length-aware decode path. The
@@ -176,10 +243,9 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
     statistics with the LSE merge. Token-exact vs the sequential walk; the
     engine guarantees every bucket divides by ``kv_shards``.
 
-    Deliberately a twin of ``block_decode`` rather than its replacement: the
-    vmapped per-row writes and (B,S) masks cost measurably more than the
-    shared-cursor path, which stays on the uniform fast form (drain serving,
-    pipeline decode). Keep the bodies in sync — the equality
+    A twin of ``block_decode`` (shared cursor, per-layer slices) rather
+    than its replacement: drain serving, the hybrid family and pipeline
+    decode keep that form. Keep the math in sync — the equality
     decode_step == decode_step_slotted under a uniform cursor is enforced by
     tests/test_serving_scheduler.py."""
     from repro.kv.cache import (batch_valid_mask, layer_append_slotted,
@@ -188,22 +254,30 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
                                 layer_read_tiered_shards)
     from repro.models.attention import decode_attention_split
     B = x.shape[0]
-    tiered = len(kv_slices) == 6
-    if tiered:
-        k_l, v_l, ks_l, vs_l, hk_l, hv_l = kv_slices
-    else:
-        k_l, v_l, ks_l, vs_l = kv_slices
-        hk_l = hv_l = None
+    tiered = cache.is_tiered
     if window:
         kv_bucket = 0                       # ring buffers have no prefix order
         kv_shards = 1                       # ... and no contiguous shard cut
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
     q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions[:, None])
+
+    def write(c, rows, shared, _row0):
+        k_new, v_new, pos, act = rows
+        at = (shared[0], pos)
+        if tiered:
+            kv = layer_append_tiered(c.k, c.v, c.k_scale, c.v_scale,
+                                     c.hot_k, c.hot_v, k_new, v_new, at,
+                                     cfg.kv_cold_dtype, act)
+        else:
+            kv = layer_append_slotted(c.k, c.v, c.k_scale, c.v_scale, k_new,
+                                      v_new, at, window, act) + (None, None)
+        return c._replace(**dict(zip(_KV_LEAVES, kv)))
+
+    cache = _write_kv(ctx, cache, write, (k[:, 0], v[:, 0], positions, active),
+                      (layer,))
+    k_l, v_l, ks_l, vs_l, hk_l, hv_l = _layer_slices(cache, layer, ctx)
     if tiered:
-        k_l, v_l, ks_l, vs_l, hk_l, hv_l = layer_append_tiered(
-            k_l, v_l, ks_l, vs_l, hk_l, hv_l, k[:, 0], v[:, 0], positions,
-            cfg.kv_cold_dtype, active)
         counts = positions + 1              # append→attend: row b has p+1 toks
         if kv_shards > 1:
             kc, vc = layer_read_tiered_shards(
@@ -216,8 +290,6 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
                 cfg.hot_window, cfg.kv_cold_block, cfg.kv_cold_dtype,
                 dtype=x.dtype)
     else:
-        k_l, v_l, ks_l, vs_l = layer_append_slotted(
-            k_l, v_l, ks_l, vs_l, k[:, 0], v[:, 0], positions, window, active)
         if kv_shards > 1:
             kc, vc = layer_read_shards(k_l, v_l, ks_l, vs_l, kv_bucket,
                                        kv_shards, dtype=x.dtype)
@@ -242,40 +314,34 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
     h = ctx.ann(h, "batch", "seq", "embed")
     f, _ = _mix_ffn(p, h, cfg, ctx, train=False)
     x = ctx.ann(x + f, "batch", "seq", "embed_shard")
-    if tiered:
-        return x, (k_l, v_l, ks_l, vs_l, hk_l, hv_l)
-    return x, (k_l, v_l, ks_l, vs_l)
+    return x, cache
 
 
 def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
-                        ctx: ShardingCtx, kv_slices: Tuple,
+                        ctx: ShardingCtx, cache: KVCache, layer,
                         slot: jax.Array, start: jax.Array,
-                        valid_len: jax.Array) -> Tuple[jax.Array, Tuple]:
-    """Chunk-prefill block over ONE layer's cache slices (DESIGN.md §7
-    chunked-prefill lane). x: (1,C,D) — slot ``slot``'s prompt chunk with
-    absolute positions [start, start+C). Writes the chunk's K/V at its
-    per-slot offset (``layer_write_chunk``; positions >= valid_len are
-    last-chunk padding and never touch the cache), reads the slot's full
-    prefix back from the STORED buffers (int8 caches dequantize — the same
-    values every later decode step will attend) and runs causal chunk
-    attention against it. slot/start/valid_len are traced: one compiled
-    program serves every chunk of every prompt. Non-windowed caches only
-    (ring order has no stable per-position offset to write at)."""
+                        valid_len: jax.Array) -> Tuple[jax.Array, KVCache]:
+    """Chunk-prefill block for layer ``layer`` of the carried ``cache``
+    stacks (DESIGN.md §7 chunked-prefill lane). x: (1,C,D) — slot
+    ``slot``'s prompt chunk with absolute positions [start, start+C).
+    Writes the chunk's K/V in place at (layer, slot, start)
+    (``layer_write_chunk``; positions >= valid_len are last-chunk padding
+    and never touch the cache), reads the slot's full prefix back from the
+    STORED buffers (int8 caches dequantize — the same values every later
+    decode step will attend) and runs causal chunk attention against it.
+    slot/start/valid_len are traced: one compiled program serves every
+    chunk of every prompt. Non-windowed caches only (ring order has no
+    stable per-position offset to write at). Returns (x', cache')."""
     from repro.kv.cache import (chunk_hot_image, cold_boundary,
                                 layer_read_slot, layer_read_slot_cold,
                                 layer_write_chunk, layer_write_chunk_tiered)
     from repro.models.attention import chunk_attention, chunk_attention_tiered
     _, C, _ = x.shape
-    tiered = len(kv_slices) == 6
-    if tiered:
-        k_l, v_l, ks_l, vs_l, hk_l, hv_l = kv_slices
-    else:
-        k_l, v_l, ks_l, vs_l = kv_slices
     positions = start + jnp.arange(C, dtype=jnp.int32)[None]          # (1,C)
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
     q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions)
-    S = k_l.shape[2]
+    S = cache.k.shape[3]
     k_ch = jnp.swapaxes(k[0], 0, 1)                              # (n_kv,C,hd)
     v_ch = jnp.swapaxes(v[0], 0, 1)
     # causal over absolute positions: query i attends cache slots <= start+i
@@ -283,15 +349,36 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     # outputs are discarded; valid queries only ever reach real positions)
     mask = jnp.arange(S, dtype=jnp.int32)[None, :] \
         <= positions[0][:, None]                                      # (C,S)
+    tiered = cache.is_tiered
     if tiered:
         # exact hot image from the PRE-write ring + the incoming chunk (the
         # write below may overwrite exactly the ring slots early queries'
-        # hot tails live in), then stage the chunk into both tiers
+        # hot tails live in)
+        *_, hk_l, hv_l = _layer_slices(cache, layer, ctx)
         kh, vh = chunk_hot_image(hk_l, hv_l, k_ch, v_ch, slot, start,
                                  valid_len, S, dtype=x.dtype)
-        k_l, v_l, ks_l, vs_l, hk_l, hv_l = layer_write_chunk_tiered(
-            k_l, v_l, ks_l, vs_l, hk_l, hv_l, k_ch, v_ch, slot, start,
-            valid_len, cfg.kv_cold_dtype)
+
+    def write(c, _rows, shared, row0):
+        # stage the chunk into the slot's row (both tiers when tiered); a
+        # batch shard that does not hold the slot rewrites nothing
+        layer_, slot_, start_, valid_, k_ch_, v_ch_ = shared
+        local = slot_ - row0
+        rows = c.k.shape[1]
+        valid_ = jnp.where((local >= 0) & (local < rows), valid_, 0)
+        at = (layer_, jnp.clip(local, 0, rows - 1))
+        if tiered:
+            kv = layer_write_chunk_tiered(c.k, c.v, c.k_scale, c.v_scale,
+                                          c.hot_k, c.hot_v, k_ch_, v_ch_, at,
+                                          start_, valid_, cfg.kv_cold_dtype)
+        else:
+            kv = layer_write_chunk(c.k, c.v, c.k_scale, c.v_scale, k_ch_,
+                                   v_ch_, at, start_, valid_) + (None, None)
+        return c._replace(**dict(zip(_KV_LEAVES, kv)))
+
+    cache = _write_kv(ctx, cache, write,
+                      shared=(layer, slot, start, valid_len, k_ch, v_ch))
+    k_l, v_l, ks_l, vs_l, _, _ = _layer_slices(cache, layer, ctx)
+    if tiered:
         kc, vc = layer_read_slot_cold(k_l, v_l, ks_l, vs_l, slot,
                                       cfg.kv_cold_dtype, dtype=x.dtype)
         kh = ctx.ann(kh, "batch", "kv_heads", "kv_seq", "head_dim")
@@ -304,8 +391,6 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
                                   cfg.kv_cold_block)[:, None])[None]  # (1,C,S)
         o = chunk_attention_tiered(q, kh, vh, kc, vc, hot_mask, mask, ctx)
     else:
-        k_l, v_l, ks_l, vs_l = layer_write_chunk(
-            k_l, v_l, ks_l, vs_l, k_ch, v_ch, slot, start, valid_len)
         kc, vc = layer_read_slot(k_l, v_l, ks_l, vs_l, slot, dtype=x.dtype)
         kc = ctx.ann(kc, "batch", "kv_heads", "kv_seq", "head_dim")
         vc = ctx.ann(vc, "batch", "kv_heads", "kv_seq", "head_dim")
@@ -316,9 +401,7 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     h = ctx.ann(h, "batch", "seq", "embed")
     f, _ = _mix_ffn(p, h, cfg, ctx, train=False)
     x = ctx.ann(x + f, "batch", "seq", "embed_shard")
-    if tiered:
-        return x, (k_l, v_l, ks_l, vs_l, hk_l, hv_l)
-    return x, (k_l, v_l, ks_l, vs_l)
+    return x, cache
 
 
 # ---------------------------------------------------------------------------
@@ -471,8 +554,10 @@ def decode_step(params, cache: KVCache, tokens: jax.Array, cfg: ModelConfig,
                 ctx: ShardingCtx) -> Tuple[KVCache, jax.Array]:
     """tokens: (B,) last emitted token ids → (cache', logits (B,1,V)).
 
-    The layer scan consumes per-layer cache slices as xs and emits updated
-    slices as ys — each layer touches only its own (B,n_kv,S,hd) slice."""
+    Drain serving's shared-cursor step. Its layer scan still takes the
+    per-layer cache slices as xs and emits the updated slices as ys, which
+    rebuilds both stacks every step; the serving programs carry the stacks
+    instead (``_layer_loop``)."""
     x = common.embed(params["embed"], tokens[:, None], ctx)
     pos = cache.length
     if cfg.pos == "learned":
@@ -505,6 +590,28 @@ def decode_step(params, cache: KVCache, tokens: jax.Array, cfg: ModelConfig,
     return cache, logits
 
 
+def _layer_loop(params, x: jax.Array, cache: KVCache, block
+                ) -> Tuple[jax.Array, KVCache]:
+    """The layer loop of the serving programs (slotted decode and the chunk
+    program). The KV stacks ride in the scan CARRY beside the hidden state,
+    never as xs/ys: ``block(lp, h, cache, l) -> (h, cache)`` writes layer
+    l's new tokens into the stacks in place at index l and reads its own
+    slice back. A donated cache therefore aliases from program entry to
+    exit — no stack is built, zeroed, sliced out per layer or copied back.
+    Serves every cache variant (bf16, int8 + scales, tiered hot/cold,
+    windowed ring, split-KV reads)."""
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+
+    def body(carry, xs):
+        h, c = carry
+        lp, layer = xs
+        return block(lp, h, c, layer), None
+
+    (x, cache), _ = jax.lax.scan(body, (x, cache), (params["blocks"], layers),
+                                 unroll=common.scan_unroll())
+    return x, cache
+
+
 def decode_step_slotted(params, cache: KVCache, tokens: jax.Array,
                         positions: jax.Array, active: jax.Array,
                         cfg: ModelConfig, ctx: ShardingCtx,
@@ -514,43 +621,24 @@ def decode_step_slotted(params, cache: KVCache, tokens: jax.Array,
     active: (B,). Mirrors ``decode_step`` but each row carries its OWN
     cursor: row b appends at positions[b] and attends 0..positions[b]; the
     shared ``cache.length`` is kept only as an upper bound. Equal to
-    ``decode_step`` when all rows share one cursor and are active.
+    ``decode_step`` when all rows share one cursor and are active. The KV
+    stacks are carried through ``_layer_loop`` and written in place.
     ``kv_bucket``: static length-aware KV extent; ``kv_shards``: static
     split-KV shard count (see block_decode_slotted)."""
     x = common.embed(params["embed"], tokens[:, None], ctx)
     if cfg.pos == "learned":
         x = x + jnp.take(params["pos_embed"], positions,
                          axis=0)[:, None].astype(x.dtype)
-    scales = cache.k_scale is not None
-    tiered = cache.is_tiered
 
-    def body(h, xs):
-        lp, k_l, v_l = xs[0], xs[1], xs[2]
-        rest = list(xs[3:])
-        ks_l, vs_l = (rest.pop(0), rest.pop(0)) if scales else (None, None)
-        if tiered:
-            hk_l, hv_l = rest
-            slices = (k_l, v_l, ks_l, vs_l, hk_l, hv_l)
-        else:
-            slices = (k_l, v_l, ks_l, vs_l)
-        h, slices = block_decode_slotted(
-            lp, h, cfg, ctx, slices, positions, active,
+    def block(lp, h, c, layer):
+        return block_decode_slotted(
+            lp, h, cfg, ctx, c, layer, positions, active,
             window=cache.window, kv_bucket=kv_bucket, kv_shards=kv_shards)
-        ys = tuple(s for s in slices if s is not None)
-        return h, ys
 
-    xs = (params["blocks"], cache.k, cache.v) + \
-        ((cache.k_scale, cache.v_scale) if scales else ()) + \
-        ((cache.hot_k, cache.hot_v) if tiered else ())
-    x, ys = jax.lax.scan(body, x, xs, unroll=common.scan_unroll())
-    ys = list(ys)
-    k_new, v_new = ys.pop(0), ys.pop(0)
-    ks_new, vs_new = (ys.pop(0), ys.pop(0)) if scales else (None, None)
-    hk_new, hv_new = (ys.pop(0), ys.pop(0)) if tiered else (None, None)
+    x, cache = _layer_loop(params, x, cache, block)
     new_len = jnp.maximum(
         cache.length, jnp.max(jnp.where(active, positions, 0)) + 1)
-    cache = cache._replace(k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new,
-                           hot_k=hk_new, hot_v=hv_new, length=new_len)
+    cache = cache._replace(length=new_len)
     x = common.apply_norm(cfg.norm, params["ln_f"], x, cfg.norm_eps)
     logits = common.unembed_logits(unembed_table(params, cfg), x, ctx)
     return cache, logits
@@ -567,7 +655,8 @@ def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
     Returns (cache', logits (1,1,V)) — logits at the chunk's LAST VALID
     position, meaningful only on a prompt's final chunk (the first decoded
     token). slot/start/valid_len are traced scalars: zero retracing across
-    chunks, prompts and slots."""
+    chunks, prompts and slots. The KV stacks are carried through
+    ``_layer_loop`` and written in place."""
     if cache.window:
         raise ValueError("chunked prefill requires a non-windowed cache "
                          "(ring order has no per-position write offset)")
@@ -580,22 +669,6 @@ def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
     elif cfg.pos == "sinusoidal":
         table = common.sinusoidal_pos(cache.k.shape[3], cfg.d_model)
         x = x + jnp.take(table, positions, axis=0)[None].astype(x.dtype)
-    scales = cache.k_scale is not None
-    tiered = cache.is_tiered
-
-    def body(h, xs):
-        lp, k_l, v_l = xs[0], xs[1], xs[2]
-        rest = list(xs[3:])
-        ks_l, vs_l = (rest.pop(0), rest.pop(0)) if scales else (None, None)
-        if tiered:
-            hk_l, hv_l = rest
-            slices = (k_l, v_l, ks_l, vs_l, hk_l, hv_l)
-        else:
-            slices = (k_l, v_l, ks_l, vs_l)
-        h, slices = block_prefill_chunk(
-            lp, h, cfg, ctx, slices, slot, start, valid_len)
-        ys = tuple(s for s in slices if s is not None)
-        return h, ys
 
     # pin the cache stacks to their planned layout at program ENTRY: GSPMD
     # infers each program's cache placement independently, and on a
@@ -603,23 +676,24 @@ def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
     # batch-REPLICATED while the decode programs compiled it batch-sharded —
     # one full-cache reshard per admission boundary on the donated buffer
     # (caught by the repro.analysis residency pass; invisible at data=1)
-    k_st = ctx.ann(cache.k, None, "batch", "kv_heads", "kv_seq", "head_dim")
-    v_st = ctx.ann(cache.v, None, "batch", "kv_heads", "kv_seq", "head_dim")
-    xs = (params["blocks"], k_st, v_st) + \
-        ((ctx.ann(cache.k_scale, None, "batch", "kv_heads", "kv_seq", None),
-          ctx.ann(cache.v_scale, None, "batch", "kv_heads", "kv_seq", None))
-         if scales else ()) + \
-        ((ctx.ann(cache.hot_k, None, "batch", "kv_heads", None, "head_dim"),
-          ctx.ann(cache.hot_v, None, "batch", "kv_heads", None, "head_dim"))
-         if tiered else ())
-    x, ys = jax.lax.scan(body, x, xs, unroll=common.scan_unroll())
-    ys = list(ys)
-    k_new, v_new = ys.pop(0), ys.pop(0)
-    ks_new, vs_new = (ys.pop(0), ys.pop(0)) if scales else (None, None)
-    hk_new, hv_new = (ys.pop(0), ys.pop(0)) if tiered else (None, None)
-    new_len = jnp.maximum(cache.length, start + valid_len)
-    cache = cache._replace(k=k_new, v=v_new, k_scale=ks_new, v_scale=vs_new,
-                           hot_k=hk_new, hot_v=hv_new, length=new_len)
+    def pin(a, *dims):
+        return None if a is None else ctx.ann(a, None, *dims)
+
+    kv = ("batch", "kv_heads", "kv_seq", "head_dim")
+    cache = cache._replace(
+        k=pin(cache.k, *kv), v=pin(cache.v, *kv),
+        k_scale=pin(cache.k_scale, *kv[:3], None),
+        v_scale=pin(cache.v_scale, *kv[:3], None),
+        hot_k=pin(cache.hot_k, "batch", "kv_heads", None, "head_dim"),
+        hot_v=pin(cache.hot_v, "batch", "kv_heads", None, "head_dim"))
+
+    def block(lp, h, c, layer):
+        return block_prefill_chunk(lp, h, cfg, ctx, c, layer, slot, start,
+                                   valid_len)
+
+    x, cache = _layer_loop(params, x, cache, block)
+    cache = cache._replace(length=jnp.maximum(cache.length,
+                                              start + valid_len))
     x = common.apply_norm(cfg.norm, params["ln_f"], x, cfg.norm_eps)
     last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
     logits = common.unembed_logits(unembed_table(params, cfg), last, ctx)

@@ -663,7 +663,9 @@ class ExecutorBackend:
         return self._reset is not None
 
     def fresh(self):
-        """Fresh slot caches for a new run (AOT programs persist)."""
+        """Fresh slot caches for a new run (AOT programs persist). The last
+        run's caches are released first: two KV stacks never coexist."""
+        self.caches = None
         self.caches = self.api.init_caches(self.slots,
                                            self.prompt_len + self.max_new_cap)
 
@@ -1700,6 +1702,7 @@ class ServingEngine:
     def _run_continuous(self, params, requests, max_steps):
         T = self.block_size
         ex = self._ex
+        self._caches = None      # the last run's caches go before fresh() allocates
         ex.fresh()
         sched = SlotScheduler(self.slots, requests, self.queue)
         self._sched = sched
