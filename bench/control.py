@@ -35,7 +35,7 @@ def readings(cfg, mix, reference, seed, api, n_requests,
     seed."""
     from bench import check, harness, traffic_gen
     from bench import weights as W
-    params = harness.make_weights(cfg, api, seed)
+    params = harness.make_weights(harness.architecture(cfg), cfg, api, seed)
     engine = harness.make_engine(cfg, mix, api)
     reqs = harness.requests(traffic_gen.draw(
         mix, n_requests, seed, traffic_gen.MEASURED, cfg["vocab_size"]))
@@ -78,7 +78,7 @@ def main(argv=None):
     require_chips(wl["chips"])
     enable_cache()
     from repro.models import build_model
-    api = build_model(harness.model_config(cfg))
+    api = build_model(harness.architecture(cfg).program_config(cfg))
     reference = harness.load_module("references", cfg["reference"])
     rows = []
     for seed in args.seeds:

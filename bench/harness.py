@@ -3,9 +3,12 @@ the correctness check, for one cell of ``BENCHMARK.json``.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by name (``find``): ``configs/<name>.json``,
-``traffic/<name>.json``, ``metrics/<name>.py``, ``references/<name>.py``,
-``cells/<workload>.json`` (the cell's limits) and ``devices.json`` (peaks by
-device kind). A new cell, mix, configuration or metric is a new file.
+``architectures/<name>.py`` (what a configuration's architecture means to
+the program, its weights and its needed work; see ``architectures/
+dense_gqa.py``), ``traffic/<name>.json``, ``metrics/<name>.py``,
+``references/<name>.py``, ``cells/<workload>.json`` (the cell's limits) and
+``devices.json`` (peaks by device kind). A new cell, mix, configuration,
+architecture or metric is a new file.
 
 The entry the window drives is ``ServingEngine.run`` of the program under
 test: colocated backend, continuous scheduler, decode blocks of
@@ -30,7 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import check, e2e, trace_reduce, traffic_gen, work
+from bench import check, e2e, trace_reduce, traffic_gen
 from bench import weights as W
 
 BENCH = Path(__file__).resolve().parent
@@ -43,7 +46,8 @@ TRACE_AFTER, TRACE_FOR_MAX = 0.2, 3.0   # traced slice: start (share), length
 # -- discovery ------------------------------------------------------------
 
 def find(kind: str, name: str, root: Path = BENCH) -> Path:
-    suffix = ".py" if kind in ("metrics", "references") else ".json"
+    suffix = ".py" if kind in ("metrics", "references",
+                               "architectures") else ".json"
     path = root / kind / f"{name}{suffix}"
     if not path.is_file():
         raise FileNotFoundError(f"no {kind} file for {name!r} at {path}")
@@ -63,6 +67,11 @@ def load_module(kind: str, name: str, root: Path = BENCH):
     return mod
 
 
+def architecture(cfg: dict, root: Path = BENCH):
+    """The module of a configuration's ``"architecture"``."""
+    return load_module("architectures", cfg["architecture"], root)
+
+
 def peaks_for(device_kind: str, root: Path = BENCH) -> dict:
     table = json.loads((root / "devices.json").read_text())
     if device_kind not in table:
@@ -77,29 +86,13 @@ def benchmark(root: Path = REPO) -> dict:
 
 # -- the program under test -------------------------------------------------
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"unsupported activation {cfg['hidden_act']!r}")
-    return ModelConfig(
-        name=cfg["name"], family=cfg["family"],
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        norm="rmsnorm", act="swiglu", rope_theta=cfg["rope_theta"],
-        qkv_bias=cfg["qkv_bias"], tie_embeddings=cfg["tie_word_embeddings"],
-        norm_eps=cfg["rms_norm_eps"], dtype=cfg["torch_dtype"])
-
-
-def make_weights(cfg: dict, api, seed: int):
+def make_weights(arch, cfg: dict, api, seed: int):
     """The served weights, made on the device by one compiled call."""
     import jax
     shapes = jax.eval_shape(api.init, jax.random.key(0))
     dtype = jax.numpy.dtype(cfg["torch_dtype"])
-    make = jax.jit(lambda key: W.to_program_tree(W.stacked(cfg, key, dtype),
-                                                 shapes))
+    make = jax.jit(lambda key: W.to_program_tree(
+        arch, W.stacked(arch, cfg, key, dtype), shapes))
     return jax.block_until_ready(make(W.root_key(seed)))
 
 
@@ -195,13 +188,14 @@ class Recorder:
     each time at a decode-block dispatch (when the previous program has
     been synced), marks every dispatch in between in the trace, and counts
     the work each decode block needs from the slots' cursors and budgets
-    as the block is dispatched. Starting and stopping the profiler stalls
-    the engine for as long as the profiler takes to collect the slice (up
-    to about a minute); ``stall_s`` is that time, which the per-layer
-    readers leave out of the window."""
+    as the block is dispatched, one micro-step at a time, by the
+    architecture's ``micro_step_need``. Starting and stopping the profiler
+    stalls the engine for as long as the profiler takes to collect the
+    slice (up to about a minute); ``stall_s`` is that time, which the
+    per-layer readers leave out of the window."""
 
-    def __init__(self, cfg: dict, seconds: float, trace_dir: str):
-        self.cfg, self.dir = cfg, trace_dir
+    def __init__(self, cfg: dict, arch, seconds: float, trace_dir: str):
+        self.cfg, self.arch, self.dir = cfg, arch, trace_dir
         self.offset = TRACE_AFTER * seconds
         self.length = min(TRACE_FOR_MAX, 0.3 * seconds)
         self.engine = None
@@ -213,8 +207,6 @@ class Recorder:
         # a fault of the count, raised after the window: an exception in
         # the dispatch hook would reach the engine's retry logic instead
         self.error: Optional[str] = None
-        self.kv_bpt = work.kv_bytes_per_token(cfg)
-        self.w_bytes = work.decode_weight_bytes(cfg)
 
     def on_dispatch(self, name: str):
         if not self.armed:
@@ -255,15 +247,14 @@ class Recorder:
         except AttributeError as e:
             self.error = self.error or f"slot cursors unreadable: {e}"
             return
-        micro = 0
-        for i in live:
-            n = int(min(BLOCK_SIZE, rem[i]))
-            micro = max(micro, n)
-            for t in range(n):
-                ctx = int(pos[i]) + t + 1
-                self.need["bytes"] += ctx * self.kv_bpt
-                self.need["flops"] += work.token_flops(self.cfg, ctx, True)
-        self.need["bytes"] += micro * self.w_bytes
+        # row i produces min(BLOCK_SIZE, remaining) tokens, the t-th of them
+        # at micro-step t, attending its cursor + t + 1 positions
+        n = {int(i): int(min(BLOCK_SIZE, rem[i])) for i in live}
+        for t in range(max(n.values(), default=0)):
+            nbytes, flops = self.arch.micro_step_need(
+                self.cfg, [int(pos[i]) + t + 1 for i in n if n[i] > t])
+            self.need["bytes"] += nbytes
+            self.need["flops"] += flops
         self.need["blocks"] += 1
 
     def stop(self):
@@ -279,6 +270,7 @@ class Recorder:
 class Window:
     """What the per-layer metric readers read."""
     cfg: dict
+    arch: object                            # the configuration's module
     peaks: dict
     slots: int
     block_size: int
@@ -344,11 +336,12 @@ def measure(cfg: dict, mix: dict, limits: dict, reference, seed: int,
     from repro.models import build_model
 
     dev = devices[0]
-    api = build_model(model_config(cfg))
-    params = make_weights(cfg, api, seed)
+    arch = architecture(cfg)
+    api = build_model(arch.program_config(cfg))
+    params = make_weights(arch, cfg, api, seed)
     note(f"weights made {time.monotonic() - t_process:.3f} s after start")
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if traced else None
-    rec = Recorder(cfg, seconds, trace_dir) if traced else None
+    rec = Recorder(cfg, arch, seconds, trace_dir) if traced else None
     engine = make_engine(cfg, mix, api, injector=rec)
     if rec is not None:
         rec.engine = engine
@@ -418,8 +411,9 @@ def measure(cfg: dict, mix: dict, limits: dict, reference, seed: int,
              f"{time.monotonic() - t0:.3f} s")
         shutil.rmtree(trace_dir, ignore_errors=True)
         note(f"the profiler stalled the window for {rec.stall_s:.3f} s")
-        w = Window(cfg=cfg, peaks=peaks, slots=conc, block_size=BLOCK_SIZE,
-                   stats=stats, window_s=window_s - rec.stall_s,
+        w = Window(cfg=cfg, arch=arch, peaks=peaks, slots=conc,
+                   block_size=BLOCK_SIZE, stats=stats,
+                   window_s=window_s - rec.stall_s,
                    completed=[(len(r.prompt), len(r.generated))
                               for r in done],
                    modules=program_modules(engine), trace=trace,

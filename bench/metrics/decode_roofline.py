@@ -1,8 +1,10 @@
 """Share of the decode-block program's device time that the work decode
 needs would take at the chip's peaks, in the traced window. The needed
-work of each block (bench/work.py) is the weights once per micro-step that
-produced a token, the KV of each generated token's true context and the
-operations, at the configuration's dtype. Moves output_tokens_per_s."""
+work of each block is the sum of the architecture module's
+``micro_step_need`` over the block's micro-steps that produced a token (for
+a dense model: the weights once, the KV of each generated token's true
+context and the operations, at the configuration's dtype). Moves
+output_tokens_per_s."""
 
 
 def read(w):

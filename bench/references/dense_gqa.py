@@ -13,7 +13,8 @@ Departures from the published models: InternLM2 stores q, k and v as one
 interleaved ``wqkv`` matrix, which with random weights computes the same
 function as three separate ones; its dynamic RoPE scaling acts only past
 32,768 positions, beyond every context served here. Weights are random
-(``bench/weights.py``), so the numbers say nothing of the trained models.
+(``bench/weights.py``, in the layout of the configuration's architecture
+module), so the numbers say nothing of the trained models.
 
 The forward pass runs one layer at a time: each layer's weights are made
 from the seed inside the layer's own program and dropped after it, so
@@ -36,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from bench import harness
 from bench import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -128,7 +130,7 @@ def block(cfg, w, h, positions, quant):
 @partial(jax.jit, static_argnums=(0, 3))
 def _embed(cfg_items, key, tokens, quant):
     cfg = dict(cfg_items)
-    table = W.global_leaf(cfg, key, "embed")
+    table = W.global_leaf(harness.architecture(cfg), cfg, key, "embed")
     if quant is not None:
         table = QUANT[quant](table, 1)
     return jnp.take(table, tokens, axis=0)
@@ -138,15 +140,18 @@ def _embed(cfg_items, key, tokens, quant):
 def _layer(cfg_items, key, index, h, quant):
     cfg = dict(cfg_items)
     positions = jnp.broadcast_to(jnp.arange(h.shape[1]), h.shape[:2])
-    return block(cfg, W.layer(cfg, key, index), h, positions, quant)
+    w = W.layer(harness.architecture(cfg), cfg, key, index)
+    return block(cfg, w, h, positions, quant)
 
 
 @partial(jax.jit, static_argnums=(0, 3))
 def _head(cfg_items, key, h_at, quant):
     cfg = dict(cfg_items)
-    x = rms_norm(h_at, W.global_leaf(cfg, key, "ln_f"), cfg["rms_norm_eps"])
+    arch = harness.architecture(cfg)
+    x = rms_norm(h_at, W.global_leaf(arch, cfg, key, "ln_f"),
+                 cfg["rms_norm_eps"])
     name = "embed" if cfg["tie_word_embeddings"] else "unembed"
-    return linear(x, W.global_leaf(cfg, key, name).T, quant)
+    return linear(x, W.global_leaf(arch, cfg, key, name).T, quant)
 
 
 def _items(cfg):

@@ -9,7 +9,8 @@ from bench import control, harness, tiny
 @pytest.fixture(scope="module")
 def api():
     from repro.models import build_model
-    return build_model(harness.model_config(tiny.CONFIG))
+    return build_model(
+        harness.architecture(tiny.CONFIG).program_config(tiny.CONFIG))
 
 
 @pytest.mark.parametrize("seed", [2, 5, 2**31 + 11])

@@ -2,7 +2,7 @@
 known, and each returns nothing where it finds nothing to read."""
 import pytest
 
-from bench import harness, work
+from bench import harness
 from bench import trace_reduce as T
 
 MS = 1_000_000
@@ -18,7 +18,7 @@ def _window(trace=True, need=None):
                      [0, T.OPS, "fusion", 50 * MS, 10 * MS]],
           "host": []}
     return harness.Window(
-        cfg=cfg, peaks=PEAKS, slots=4, block_size=8,
+        cfg=cfg, arch=harness.architecture(cfg), peaks=PEAKS, slots=4, block_size=8,
         stats={"macro_steps": 10, "decode_tokens": 80,
                "prefill_time_ms": 250.0},
         window_s=2.0, completed=[(100, 10), (50, 20)],
@@ -35,8 +35,9 @@ def test_counter_readers():
     w = _window()
     assert _read("decode_occupancy", w) == pytest.approx(25.0)  # 80/320
     assert _read("prefill_share", w) == pytest.approx(12.5)     # 0.25/2
-    flops = (work.prompt_flops(w.cfg, 100) + work.decode_flops(w.cfg, 100, 10)
-             + work.prompt_flops(w.cfg, 50) + work.decode_flops(w.cfg, 50, 20))
+    a = w.arch
+    flops = (a.prompt_flops(w.cfg, 100) + a.decode_flops(w.cfg, 100, 10)
+             + a.prompt_flops(w.cfg, 50) + a.decode_flops(w.cfg, 50, 20))
     assert _read("mfu", w) == pytest.approx(100 * flops / 2.0 / 100e12)
 
 

@@ -11,6 +11,7 @@ from bench import harness
 from bench import weights as W
 
 REF = harness.load_module("references", "dense_gqa")
+ARCH = harness.load_module("architectures", "dense_gqa")
 
 
 def _small(name, **kw):
@@ -32,11 +33,11 @@ def _program_logits(cfg, seed, tokens):
     """All-position logits of the program's full-sequence forward, in f32
     at the highest precision, on the benchmark's weights."""
     from repro.models import NULL_CTX, build_model, transformer
-    mcfg = harness.model_config(dict(cfg, torch_dtype="float32"))
+    mcfg = ARCH.program_config(dict(cfg, torch_dtype="float32"))
     api = build_model(mcfg)
     shapes = jax.eval_shape(api.init, jax.random.key(0))
-    params = W.to_program_tree(W.stacked(cfg, W.root_key(seed), jnp.float32),
-                               shapes)
+    params = W.to_program_tree(
+        ARCH, W.stacked(ARCH, cfg, W.root_key(seed), jnp.float32), shapes)
     with jax.default_matmul_precision("highest"):
         h, _ = transformer.forward_hidden(params, jnp.asarray(tokens), mcfg,
                                           NULL_CTX, train=False)
@@ -72,14 +73,14 @@ def test_right_padding_changes_no_scored_position():
 def test_weights_exact_in_bf16_and_equal_layer_by_layer():
     cfg = CASES["internlm2-like"]
     key = W.root_key(2**33 + 7)
-    whole = W.stacked(cfg, key, jnp.float32)
+    whole = W.stacked(ARCH, cfg, key, jnp.float32)
     for name, leaf in whole.items():
         assert jnp.array_equal(leaf.astype(jnp.bfloat16).astype(jnp.float32),
                                leaf), name
-    one = jax.jit(lambda k, i: W.layer(cfg, k, i))(key, jnp.uint32(1))
+    one = jax.jit(lambda k, i: W.layer(ARCH, cfg, k, i))(key, jnp.uint32(1))
     for name, leaf in one.items():
         assert jnp.array_equal(leaf, whole[name][1]), name
-    assert jnp.array_equal(W.global_leaf(cfg, key, "unembed"),
+    assert jnp.array_equal(W.global_leaf(ARCH, cfg, key, "unembed"),
                            whole["unembed"])
 
 

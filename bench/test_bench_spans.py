@@ -16,8 +16,10 @@ PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
 def _window(stats):
-    return harness.Window(cfg=tiny.CONFIG, peaks=PEAKS, slots=2, block_size=8,
-                          stats=stats, window_s=1.0, completed=[])
+    return harness.Window(cfg=tiny.CONFIG,
+                          arch=harness.architecture(tiny.CONFIG),
+                          peaks=PEAKS, slots=2, block_size=8, stats=stats,
+                          window_s=1.0, completed=[])
 
 
 def _read(name, w):

@@ -1,13 +1,16 @@
-"""Work counts and peaks of the benchmark, pinned against the program's own
-parameter shapes (``jax.eval_shape``: no weights are made)."""
+"""Work counts of the dense architecture module and the peaks of the
+benchmark, pinned against the program's own parameter shapes
+(``jax.eval_shape``: no weights are made)."""
 import json
 import math
 
 import jax
 import pytest
 
-from bench import harness, work
+from bench import harness
 from bench import weights as W
+
+dense = harness.load_module("architectures", "dense_gqa")
 
 
 def _cfg(name):
@@ -16,7 +19,7 @@ def _cfg(name):
 
 def _program_param_bytes(cfg):
     from repro.models import build_model
-    api = build_model(harness.model_config(cfg))
+    api = build_model(dense.program_config(cfg))
     shapes = jax.eval_shape(api.init, jax.random.key(0))
     return sum(math.prod(a.shape) * a.dtype.itemsize
                for a in jax.tree.leaves(shapes))
@@ -28,27 +31,27 @@ def _program_param_bytes(cfg):
 ])
 def test_weight_and_kv_bytes(name, weights, kv):
     cfg = _cfg(name)
-    assert work.weight_bytes(cfg) == weights
+    assert dense.weight_bytes(cfg) == weights
     assert _program_param_bytes(cfg) == weights
-    assert work.kv_bytes_per_token(cfg) == kv
+    assert dense.kv_bytes_per_token(cfg) == kv
 
 
 def test_decode_weight_bytes_skip_only_an_untied_input_table():
     q, i = _cfg("qwen2-0.5b"), _cfg("internlm2-1.8b")
-    assert work.decode_weight_bytes(q) == work.weight_bytes(q)
+    assert dense.decode_weight_bytes(q) == dense.weight_bytes(q)
     table = i["vocab_size"] * i["hidden_size"] * 2
-    assert work.decode_weight_bytes(i) == work.weight_bytes(i) - table
+    assert dense.decode_weight_bytes(i) == dense.weight_bytes(i) - table
 
 
 def test_flops_add_up():
     cfg = _cfg("qwen2-0.5b")
     # a 3-token prompt is 3 tokens of context 1, 2, 3 with one unembedding
-    by_token = sum(work.token_flops(cfg, c, logits=False) for c in (1, 2, 3))
+    by_token = sum(dense.token_flops(cfg, c, logits=False) for c in (1, 2, 3))
     unembed = 2 * cfg["vocab_size"] * cfg["hidden_size"]
-    assert work.prompt_flops(cfg, 3) == by_token + unembed
+    assert dense.prompt_flops(cfg, 3) == by_token + unembed
     # 4 generated tokens: 3 decode steps at contexts 11, 12, 13
-    assert work.decode_flops(cfg, 10, 4) == sum(
-        work.token_flops(cfg, c, logits=True) for c in (11, 12, 13))
+    assert dense.decode_flops(cfg, 10, 4) == sum(
+        dense.token_flops(cfg, c, logits=True) for c in (11, 12, 13))
 
 
 def test_benchmark_weights_fill_the_program_tree():
@@ -57,11 +60,12 @@ def test_benchmark_weights_fill_the_program_tree():
     from repro.models import build_model
     for name in ("qwen2-0.5b", "internlm2-1.8b"):
         cfg = _cfg(name)
-        api = build_model(harness.model_config(cfg))
+        arch = harness.architecture(cfg)
+        api = build_model(arch.program_config(cfg))
         shapes = jax.eval_shape(api.init, jax.random.key(0))
         out = jax.eval_shape(
             lambda k: W.to_program_tree(
-                W.stacked(cfg, k, jax.numpy.bfloat16), shapes),
+                arch, W.stacked(arch, cfg, k, jax.numpy.bfloat16), shapes),
             W.root_key(1))
         assert jax.tree.structure(out) == jax.tree.structure(shapes)
 

@@ -3,7 +3,8 @@ CPU tests: every feature of the served configurations (GQA, QKV bias, an
 untied head, the chunk lane, decode blocks) at sizes a test run holds."""
 
 CONFIG = {
-    "name": "tiny", "family": "dense", "reference": "dense_gqa",
+    "name": "tiny", "family": "dense", "architecture": "dense_gqa",
+    "reference": "dense_gqa",
     "torch_dtype": "bfloat16", "num_hidden_layers": 4, "hidden_size": 128,
     "intermediate_size": 256, "num_attention_heads": 8,
     "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 2048,
