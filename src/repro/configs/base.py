@@ -30,6 +30,27 @@ class MoEConfig:
     # number of dense (shared) ffn units run for every token, 0 for pure MoE
     num_shared_experts: int = 0
     router_jitter: float = 0.0
+    # expert parallelism seen from one chip: the router still routes over
+    # all ``num_experts``, but this chip holds only experts
+    # [held_offset, held_offset + held_experts) and computes their part of
+    # the result, dropless (models/moe.py::moe_held). 0 → every expert is
+    # held and dispatch is the capacity-bounded path (moe_ffn)
+    held_experts: int = 0
+    held_offset: int = 0
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN scaling of a rotary table (``common.yarn_freqs``): low
+    frequencies divided by ``factor``, high ones kept, a linear ramp between
+    the dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings``; cos and sin scaled by
+    ``attention_factor``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -83,6 +104,13 @@ class ModelConfig:
     qkv_bias: bool = False
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
+    # per-layer attention kind: layer l attends the ``layer_windows[l]``
+    # positions ending at the query (itself included), 0 → every position.
+    # Masked over the one full-extent KV stack. () → every layer full
+    layer_windows: Tuple[int, ...] = ()
+    # YaRN on the full-attention layers' rotary table (windowed layers keep
+    # the plain table of rope_theta); None → plain everywhere
+    yarn: Optional[YaRNConfig] = None
     # --- optional sub-configs ---
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None

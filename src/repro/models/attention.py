@@ -519,8 +519,11 @@ def make_attn_params(key, cfg, d_model: Optional[int] = None) -> dict:
 
 
 def qkv_project(p: dict, x: jax.Array, cfg, ctx: ShardingCtx,
-                positions: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """x: (B,S,D) → q (B,S,Hq,hd), k/v (B,S,Hkv,hd) with RoPE applied.
+                positions: jax.Array, rope: Optional[Tuple] = None
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B,S,D) → q (B,S,Hq,hd), k/v (B,S,Hkv,hd) with RoPE applied;
+    ``rope`` = (inverse frequencies, cos/sin scale) replaces the plain
+    table of ``cfg.rope_theta`` (``transformer._layer_rope``).
 
     NOTE (paper §3.2 "head independence"): there is deliberately NO sharding
     annotation forcing materialization between this projection and attention —
@@ -535,8 +538,9 @@ def qkv_project(p: dict, x: jax.Array, cfg, ctx: ShardingCtx,
         q = common.apply_norm("rmsnorm", p["q_norm"], q, cfg.norm_eps)
         k = common.apply_norm("rmsnorm", p["k_norm"], k, cfg.norm_eps)
     if cfg.pos == "rope":
-        q = common.apply_rope(q, positions, cfg.rope_theta)
-        k = common.apply_rope(k, positions, cfg.rope_theta)
+        freqs, scale = rope if rope is not None else (None, None)
+        q = common.apply_rope(q, positions, cfg.rope_theta, freqs, scale)
+        k = common.apply_rope(k, positions, cfg.rope_theta, freqs, scale)
     q = ctx.ann(q, "batch", "seq", "act_heads", "head_dim")
     k = ctx.ann(k, "batch", "seq", "kv_heads", "head_dim")
     v = ctx.ann(v, "batch", "seq", "kv_heads", "head_dim")

@@ -8,10 +8,11 @@ from __future__ import annotations
 
 import math
 import os
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def scan_unroll() -> bool:
@@ -94,12 +95,41 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq) int32."""
+def yarn_freqs(head_dim: int, theta: float, yarn) -> Tuple[np.ndarray, float]:
+    """YaRN's inverse frequencies (hd/2,) and its cos/sin scale, as the
+    published ``rope_type: "yarn"`` computes them (``configs.base.
+    YaRNConfig``): dimension pairs below the one that turns ``beta_fast``
+    times over the original context keep their frequency, those past the
+    one that turns ``beta_slow`` times are divided by ``factor``, and a
+    linear ramp blends the two between (bounds floored and ceiled)."""
+    pos = theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+
+    def dim_of(turns):
+        return head_dim * math.log(yarn.original_max_position_embeddings
+                                   / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 / pos) * (1.0 - ramp) + (1.0 / (yarn.factor * pos)) * ramp
+    return inv.astype(np.float32), float(yarn.attention_factor)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               scale: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq) int32.
+    ``freqs`` (hd/2,) replaces the plain table of ``theta`` and ``scale``
+    multiplies cos and sin (YaRN, or a layer's pick of its kind's table)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                        # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                    # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., seq, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

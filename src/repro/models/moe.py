@@ -10,11 +10,15 @@ requirement) whose resharding the compiler lowers to all-to-all on the ICI.
 Routing IS sub-operator scheduling: each token's expert assignment is an
 independent dependency edge; there is no operator-boundary barrier between
 router, dispatch, expert GEMMs and combine.
+
+``moe_held`` is one chip's share of expert parallelism (``MoEConfig.
+held_experts``): the router routes over every expert, the chip computes
+only its own experts' part, dropless, and counts the picks they receive.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +29,21 @@ from repro.models.sharding import ShardingCtx
 
 
 def make_moe_params(key, cfg: ModelConfig) -> Dict:
+    """Router over all ``num_experts``; expert weights for the experts this
+    chip holds (all of them, or ``held_experts``). A held-expert router is
+    stored in the model dtype, as the published checkpoints store it, and
+    computes in float32 all the same."""
     m = cfg.moe
-    d, f, e = cfg.d_model, m.expert_d_ff, m.num_experts
+    d, f = cfg.d_model, m.expert_d_ff
+    if m.held_experts:
+        if not 0 <= m.held_offset <= m.num_experts - m.held_experts:
+            raise ValueError(
+                f"held experts [{m.held_offset}, "
+                f"{m.held_offset + m.held_experts}) lie outside the "
+                f"router's {m.num_experts}")
+        e, router_dt = m.held_experts, common.dtype_of(cfg)
+    else:
+        e, router_dt = m.num_experts, jnp.dtype(jnp.float32)
     dt = common.dtype_of(cfg)
     ks = jax.random.split(key, 4)
 
@@ -34,11 +51,55 @@ def make_moe_params(key, cfg: ModelConfig) -> Dict:
         return common.dense_init(k, shape, dt, fan_in=fan_in)
 
     return {
-        "router": common.make_linear(ks[0], d, e, jnp.dtype(jnp.float32)),
+        "router": common.make_linear(ks[0], d, m.num_experts, router_dt),
         "w_gate": einit(ks[1], (e, d, f), d),
         "w_up": einit(ks[2], (e, d, f), d),
         "w_down": einit(ks[3], (e, f, d), f),
     }
+
+
+def moe_held(p: Dict, x: jax.Array, cfg: ModelConfig,
+             valid: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """One chip's share of an expert-parallel layer, dropless. x: (B,S,D);
+    ``valid`` (B,S) bool marks the real tokens (None: all). Returns (out
+    (B,S,D), picks (held,) int32).
+
+    Every token is routed over all ``num_experts`` (float32 logits and
+    softmax), takes its top ``experts_per_token`` and renormalises their
+    weights over those; ``out`` is the weighted sum of the outputs of the
+    picked experts that this chip holds, nothing for the others: the
+    partial result that goes on to the next layer. No token is dropped:
+    the held experts run as one SwiGLU of width held x expert_d_ff over
+    every token, each expert's hidden part scaled by the token's weight for
+    it (zero where the token did not pick it). ``picks`` counts the valid
+    tokens that picked each held expert."""
+    m = cfg.moe
+    B, S, D = x.shape
+    H = m.held_experts
+    xf = x.reshape(B * S, D)
+    with jax.named_scope("moe/router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"]["w"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, m.experts_per_token)
+        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
+        # (T, K, H): which held expert each pick is; picks of experts held
+        # elsewhere match none
+        held = jax.nn.one_hot(gate_idx - m.held_offset, H, dtype=jnp.float32)
+        weights = jnp.einsum("tk,tkh->th", gate_vals, held)
+        mask = held if valid is None else \
+            held * valid.reshape(B * S, 1, 1).astype(jnp.float32)
+        picks = jnp.sum(mask, axis=(0, 1)).astype(jnp.int32)
+    with jax.named_scope("moe/experts"):
+        gate = jnp.einsum("td,hdf->thf", xf, p["w_gate"],
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("td,hdf->thf", xf, p["w_up"],
+                        preferred_element_type=jnp.float32)
+        h = jax.nn.silu(gate) * up * weights[..., None]
+        out = jnp.einsum("thf,hfd->td", h.astype(x.dtype), p["w_down"],
+                         preferred_element_type=jnp.float32)
+    return out.astype(x.dtype).reshape(B, S, D), picks
 
 
 def capacity(tokens: int, cfg: ModelConfig) -> int:

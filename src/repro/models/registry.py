@@ -82,7 +82,9 @@ def make_decode_block(decode_slotted: Callable) -> Callable:
     Returns ``(caches, toks (T,B) int32, emitted (T,B) bool, last_tok,
     positions, active, remaining)`` — ``emitted[t, b]`` marks micro-steps
     that really generated a token, so the host can unpack the block without
-    guessing which zeros are padding."""
+    guessing which zeros are padding. Where ``decode_slotted`` returns
+    counts after its logits (a held-expert layer's picks), each is summed
+    over the block's micro-steps and follows the seven."""
 
     def decode_block(params, caches, tokens, positions, active, remaining,
                      eos_ids, ctx, *, block_size: int, kv_bucket: int = 0,
@@ -93,8 +95,9 @@ def make_decode_block(decode_slotted: Callable) -> Callable:
 
         def micro(carry, _):
             caches, tok, pos, act, rem = carry
-            caches, logits = decode_slotted(params, caches, tok, pos, act,
-                                            ctx, kv_bucket=kv_bucket, **extra)
+            caches, logits, *counts = decode_slotted(
+                params, caches, tok, pos, act, ctx, kv_bucket=kv_bucket,
+                **extra)
             nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
             nxt = jnp.where(act, nxt, 0)
             emitted = act
@@ -102,12 +105,13 @@ def make_decode_block(decode_slotted: Callable) -> Callable:
             pos = pos + step
             rem = rem - step
             act = act & (rem > 0) & ((eos_ids < 0) | (nxt != eos_ids))
-            return (caches, nxt, pos, act, rem), (nxt, emitted)
+            return (caches, nxt, pos, act, rem), (nxt, emitted, *counts)
 
-        (caches, tok, pos, act, rem), (toks, emitted) = jax.lax.scan(
-            micro, (caches, tokens, positions, active, remaining),
-            None, length=block_size)
-        return caches, toks, emitted, tok, pos, act, rem
+        (caches, tok, pos, act, rem), (toks, emitted, *counts) = \
+            jax.lax.scan(micro, (caches, tokens, positions, active,
+                                 remaining), None, length=block_size)
+        return (caches, toks, emitted, tok, pos, act, rem) + \
+            tuple(jnp.sum(c, axis=0) for c in counts)
 
     return decode_block
 
@@ -159,7 +163,8 @@ def _build_transformer(cfg: ModelConfig) -> ModelAPI:
                     # VLM prompts interleave vision embeds — the token-only
                     # chunk walk cannot cover them; monolithic admission only
                     prefill_chunk=None if is_vlm else prefill_chunk,
-                    wa_servable=not is_vlm)
+                    # the W/A layer loop has no per-layer windows
+                    wa_servable=not is_vlm and not any(cfg.layer_windows))
 
 
 def _build_ssm(cfg: ModelConfig) -> ModelAPI:

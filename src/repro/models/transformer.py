@@ -59,6 +59,49 @@ def ffn_apply(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx) -> jax.
     return common.linear(p["w_down"], h)
 
 
+def _layer_rope(cfg: ModelConfig, window) -> Optional[Tuple]:
+    """The rotary table of a layer for ``qkv_project``: (inverse
+    frequencies, cos/sin scale), or None for the plain table of
+    ``rope_theta``. Full-attention layers take the YaRN table where the
+    configuration has one: every layer when ``window`` is None, the layers
+    whose traced ``window`` is 0 otherwise."""
+    if cfg.yarn is None:
+        return None
+    freqs, scale = common.yarn_freqs(cfg.head_dim, cfg.rope_theta, cfg.yarn)
+    if window is None:
+        return jnp.asarray(freqs), scale
+    full = window == 0
+    return (jnp.where(full, freqs, common.rope_freqs(cfg.head_dim,
+                                                     cfg.rope_theta)),
+            jnp.where(full, scale, 1.0))
+
+
+def _in_window(mask: jax.Array, query_pos: jax.Array, window) -> jax.Array:
+    """``mask`` (..., S) over cache positions, kept only at the ``window``
+    positions ending at each query (itself included), i.e. keys after
+    ``query_pos - window``; a window of 0 keeps it whole."""
+    keys = jnp.arange(mask.shape[-1], dtype=jnp.int32)
+    return mask & ((window == 0) | (keys > query_pos - window))
+
+
+def _windows(cfg: ModelConfig) -> Optional[jax.Array]:
+    """Each layer's attention window (L,) int32 for the layer loop, or None
+    where every layer attends its whole prefix."""
+    if not any(cfg.layer_windows):
+        return None
+    if len(cfg.layer_windows) != cfg.n_layers:
+        raise ValueError(f"{len(cfg.layer_windows)} layer windows for "
+                         f"{cfg.n_layers} layers")
+    return jnp.asarray(cfg.layer_windows, jnp.int32)
+
+
+def _refuse_layer_kinds(cfg: ModelConfig, what: str):
+    if any(cfg.layer_windows) or cfg.yarn is not None:
+        raise ValueError(f"{what} has no per-layer attention windows or "
+                         "YaRN tables; the slotted decode and chunk "
+                         "programs serve them")
+
+
 # ---------------------------------------------------------------------------
 # Transformer block
 # ---------------------------------------------------------------------------
@@ -79,9 +122,20 @@ def make_block_params(key, cfg: ModelConfig) -> dict:
     return p
 
 
+def _held(cfg: ModelConfig) -> bool:
+    """The FFN is one chip's share of an expert-parallel layer."""
+    return cfg.moe is not None and cfg.moe.held_experts > 0
+
+
 def _mix_ffn(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
-             train: bool) -> Tuple[jax.Array, jax.Array]:
-    """FFN half of the block; returns (out, aux_loss)."""
+             train: bool, valid: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, jax.Array]:
+    """FFN half of the block; returns (out, aux): the load-balance loss, or
+    for a held-expert layer the picks per held expert over the ``valid``
+    (B,S) tokens (``moe.moe_held``)."""
+    if _held(cfg):
+        from repro.models.moe import moe_held
+        return moe_held(p["moe"], x, cfg, valid)
     if cfg.moe is not None:
         from repro.models.moe import moe_ffn
         return moe_ffn(p["moe"], x, cfg, ctx, train=train)
@@ -121,6 +175,8 @@ def block_full_seq(p: dict, x: jax.Array, cfg: ModelConfig, ctx: ShardingCtx,
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
     f, aux = _mix_ffn(p, h, cfg, ctx, train)
+    if _held(cfg):
+        aux = jnp.zeros((), jnp.float32)    # picks: the serving programs'
     x = ctx.ann(x + f, "batch", "seq", "embed_shard")
     return x, (q, k, v, aux)
 
@@ -222,7 +278,8 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
                          ctx: ShardingCtx, cache: KVCache, layer,
                          positions: jax.Array, active: jax.Array,
                          window: int = 0, kv_bucket: int = 0,
-                         kv_shards: int = 1) -> Tuple[jax.Array, KVCache]:
+                         kv_shards: int = 1, attn_window=None
+                         ) -> Tuple[jax.Array, KVCache, Optional[jax.Array]]:
     """``block_decode`` with PER-ROW cursors (continuous batching) for layer
     ``layer`` of the carried ``cache`` stacks: row b writes its new K/V
     into the stacks in place at (layer, b, positions[b])
@@ -230,7 +287,13 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
     slice is read back for attention over the row's own prefix. Inactive
     rows write nothing (their KV stays byte-identical); their activations
     still flow — static shapes — but the engine masks the resulting
-    logits. Returns (x', cache').
+    logits. Returns (x', cache', picks): a held-expert layer's picks per
+    held expert over the active rows, else None.
+
+    ``attn_window`` (traced, None for a stack of full layers): this layer
+    attends only the ``attn_window`` positions ending at each row's cursor
+    (0 → all of them), masked over the full-extent stack, and takes its
+    kind's rotary table (``_layer_rope``).
 
     ``kv_bucket`` > 0 (non-windowed caches only) reads and attends only the
     first ``kv_bucket`` cache positions — the length-aware decode path. The
@@ -260,7 +323,8 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
         kv_shards = 1                       # ... and no contiguous shard cut
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
-    q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions[:, None])
+    q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions[:, None],
+                          _layer_rope(cfg, attn_window))
 
     def write(c, rows, shared, _row0):
         k_new, v_new, pos, act = rows
@@ -302,25 +366,28 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
         vc = ctx.ann(vc, "batch", "kv_heads", "kv_shard", "kv_seq",
                      "head_dim")
         mask = batch_valid_mask(kc.shape[2] * kc.shape[3], window, positions)
-        o = decode_attention_split(q[:, 0], kc, vc, mask, ctx)
     else:
         kc = ctx.ann(kc, "batch", "kv_heads", "kv_seq", "head_dim")
         vc = ctx.ann(vc, "batch", "kv_heads", "kv_seq", "head_dim")
         mask = batch_valid_mask(kc.shape[2], window, positions)    # (B,Sb)
-        o = decode_attention(q[:, 0], kc, vc, mask, ctx)
+    if attn_window is not None:
+        mask = _in_window(mask, positions[:, None], attn_window)
+    attend = decode_attention_split if kv_shards > 1 else decode_attention
+    o = attend(q[:, 0], kc, vc, mask, ctx)
     o = common.linear(p["attn"]["wo"], o.reshape(B, 1, -1))
     x = ctx.ann(x + o, "batch", "seq", "embed_shard")
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
-    f, _ = _mix_ffn(p, h, cfg, ctx, train=False)
+    f, aux = _mix_ffn(p, h, cfg, ctx, train=False, valid=active[:, None])
     x = ctx.ann(x + f, "batch", "seq", "embed_shard")
-    return x, cache
+    return x, cache, (aux if _held(cfg) else None)
 
 
 def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
                         ctx: ShardingCtx, cache: KVCache, layer,
                         slot: jax.Array, start: jax.Array,
-                        valid_len: jax.Array) -> Tuple[jax.Array, KVCache]:
+                        valid_len: jax.Array, attn_window=None
+                        ) -> Tuple[jax.Array, KVCache, Optional[jax.Array]]:
     """Chunk-prefill block for layer ``layer`` of the carried ``cache``
     stacks (DESIGN.md §7 chunked-prefill lane). x: (1,C,D) — slot
     ``slot``'s prompt chunk with absolute positions [start, start+C).
@@ -330,8 +397,11 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     STORED buffers (int8 caches dequantize — the same values every later
     decode step will attend) and runs causal chunk attention against it.
     slot/start/valid_len are traced: one compiled program serves every
-    chunk of every prompt. Non-windowed caches only (ring order has no
-    stable per-position offset to write at). Returns (x', cache')."""
+    chunk of every prompt. Non-ring caches only (ring order has no stable
+    per-position offset to write at); a per-layer ``attn_window`` masks the
+    full-extent stack as in ``block_decode_slotted``. Returns (x', cache',
+    picks): a held-expert layer's picks over the chunk's valid tokens, else
+    None."""
     from repro.kv.cache import (chunk_hot_image, cold_boundary,
                                 layer_read_slot, layer_read_slot_cold,
                                 layer_write_chunk, layer_write_chunk_tiered)
@@ -340,7 +410,8 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     positions = start + jnp.arange(C, dtype=jnp.int32)[None]          # (1,C)
     h = common.apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
-    q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions)
+    q, k, v = qkv_project(p["attn"], h, cfg, ctx, positions,
+                          _layer_rope(cfg, attn_window))
     S = cache.k.shape[3]
     k_ch = jnp.swapaxes(k[0], 0, 1)                              # (n_kv,C,hd)
     v_ch = jnp.swapaxes(v[0], 0, 1)
@@ -349,6 +420,8 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     # outputs are discarded; valid queries only ever reach real positions)
     mask = jnp.arange(S, dtype=jnp.int32)[None, :] \
         <= positions[0][:, None]                                      # (C,S)
+    if attn_window is not None:
+        mask = _in_window(mask, positions[0][:, None], attn_window)
     tiered = cache.is_tiered
     if tiered:
         # exact hot image from the PRE-write ring + the incoming chunk (the
@@ -399,9 +472,10 @@ def block_prefill_chunk(p: dict, x: jax.Array, cfg: ModelConfig,
     x = ctx.ann(x + o, "batch", "seq", "embed_shard")
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")
-    f, _ = _mix_ffn(p, h, cfg, ctx, train=False)
+    f, aux = _mix_ffn(p, h, cfg, ctx, train=False,
+                      valid=(jnp.arange(C) < valid_len)[None])
     x = ctx.ann(x + f, "batch", "seq", "embed_shard")
-    return x, cache
+    return x, cache, (aux if _held(cfg) else None)
 
 
 # ---------------------------------------------------------------------------
@@ -440,6 +514,7 @@ def forward_hidden(params, tokens: jax.Array, cfg: ModelConfig,
                    vision_embeds: Optional[jax.Array] = None,
                    collect_kv: bool = False):
     """tokens: (B,S_text). Returns (hidden (B,S,D), aux_loss[, kv list])."""
+    _refuse_layer_kinds(cfg, "the full-sequence forward")
     x = common.embed(params["embed"], tokens, ctx)
     if vision_embeds is not None:                     # VLM stub frontend
         x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
@@ -558,6 +633,7 @@ def decode_step(params, cache: KVCache, tokens: jax.Array, cfg: ModelConfig,
     per-layer cache slices as xs and emits the updated slices as ys, which
     rebuilds both stacks every step; the serving programs carry the stacks
     instead (``_layer_loop``)."""
+    _refuse_layer_kinds(cfg, "the shared-cursor decode step")
     x = common.embed(params["embed"], tokens[:, None], ctx)
     pos = cache.length
     if cfg.pos == "learned":
@@ -590,26 +666,31 @@ def decode_step(params, cache: KVCache, tokens: jax.Array, cfg: ModelConfig,
     return cache, logits
 
 
-def _layer_loop(params, x: jax.Array, cache: KVCache, block
-                ) -> Tuple[jax.Array, KVCache]:
+def _layer_loop(params, x: jax.Array, cache: KVCache, block,
+                windows: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, KVCache, Any]:
     """The layer loop of the serving programs (slotted decode and the chunk
     program). The KV stacks ride in the scan CARRY beside the hidden state,
-    never as xs/ys: ``block(lp, h, cache, l) -> (h, cache)`` writes layer
-    l's new tokens into the stacks in place at index l and reads its own
-    slice back. A donated cache therefore aliases from program entry to
-    exit — no stack is built, zeroed, sliced out per layer or copied back.
-    Serves every cache variant (bf16, int8 + scales, tiered hot/cold,
-    windowed ring, split-KV reads)."""
+    never as xs/ys: ``block(lp, h, cache, l[, window]) -> (h, cache, ys)``
+    writes layer l's new tokens into the stacks in place at index l and
+    reads its own slice back. A donated cache therefore aliases from
+    program entry to exit — no stack is built, zeroed, sliced out per layer
+    or copied back. Serves every cache variant (bf16, int8 + scales, tiered
+    hot/cold, windowed ring, split-KV reads). ``windows`` (L,), each
+    layer's attention window (``_windows``), is scanned beside its params
+    and passed to ``block``; None passes nothing. Returns (x, cache, the
+    layers' ys stacked; None where the blocks return None)."""
     layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    xs = (params["blocks"], layers) + (() if windows is None else (windows,))
 
     def body(carry, xs):
         h, c = carry
-        lp, layer = xs
-        return block(lp, h, c, layer), None
+        h, c, ys = block(xs[0], h, c, *xs[1:])
+        return (h, c), ys
 
-    (x, cache), _ = jax.lax.scan(body, (x, cache), (params["blocks"], layers),
-                                 unroll=common.scan_unroll())
-    return x, cache
+    (x, cache), ys = jax.lax.scan(body, (x, cache), xs,
+                                  unroll=common.scan_unroll())
+    return x, cache, ys
 
 
 def decode_step_slotted(params, cache: KVCache, tokens: jax.Array,
@@ -624,24 +705,27 @@ def decode_step_slotted(params, cache: KVCache, tokens: jax.Array,
     ``decode_step`` when all rows share one cursor and are active. The KV
     stacks are carried through ``_layer_loop`` and written in place.
     ``kv_bucket``: static length-aware KV extent; ``kv_shards``: static
-    split-KV shard count (see block_decode_slotted)."""
+    split-KV shard count (see block_decode_slotted). A held-expert
+    configuration returns a third output, the picks per layer and held
+    expert (L, held) int32 of the active rows."""
     x = common.embed(params["embed"], tokens[:, None], ctx)
     if cfg.pos == "learned":
         x = x + jnp.take(params["pos_embed"], positions,
                          axis=0)[:, None].astype(x.dtype)
 
-    def block(lp, h, c, layer):
+    def block(lp, h, c, layer, attn_window=None):
         return block_decode_slotted(
             lp, h, cfg, ctx, c, layer, positions, active,
-            window=cache.window, kv_bucket=kv_bucket, kv_shards=kv_shards)
+            window=cache.window, kv_bucket=kv_bucket, kv_shards=kv_shards,
+            attn_window=attn_window)
 
-    x, cache = _layer_loop(params, x, cache, block)
+    x, cache, picks = _layer_loop(params, x, cache, block, _windows(cfg))
     new_len = jnp.maximum(
         cache.length, jnp.max(jnp.where(active, positions, 0)) + 1)
     cache = cache._replace(length=new_len)
     x = common.apply_norm(cfg.norm, params["ln_f"], x, cfg.norm_eps)
     logits = common.unembed_logits(unembed_table(params, cfg), x, ctx)
-    return cache, logits
+    return (cache, logits) if picks is None else (cache, logits, picks)
 
 
 def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
@@ -656,10 +740,14 @@ def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
     position, meaningful only on a prompt's final chunk (the first decoded
     token). slot/start/valid_len are traced scalars: zero retracing across
     chunks, prompts and slots. The KV stacks are carried through
-    ``_layer_loop`` and written in place."""
+    ``_layer_loop`` and written in place. A held-expert configuration
+    returns a third output, the picks per layer and held expert (L, held)
+    int32 of the chunk's valid tokens."""
     if cache.window:
-        raise ValueError("chunked prefill requires a non-windowed cache "
-                         "(ring order has no per-position write offset)")
+        raise ValueError(
+            "chunked prefill refuses a ring cache (KVCache.window > 0): ring "
+            "order has no per-position write offset. Per-layer windows "
+            "(ModelConfig.layer_windows) mask a full-extent cache instead")
     x = common.embed(params["embed"], tokens, ctx)
     C = tokens.shape[1]
     positions = start + jnp.arange(C, dtype=jnp.int32)
@@ -687,17 +775,17 @@ def prefill_chunk(params, cache: KVCache, tokens: jax.Array, slot: jax.Array,
         hot_k=pin(cache.hot_k, "batch", "kv_heads", None, "head_dim"),
         hot_v=pin(cache.hot_v, "batch", "kv_heads", None, "head_dim"))
 
-    def block(lp, h, c, layer):
+    def block(lp, h, c, layer, attn_window=None):
         return block_prefill_chunk(lp, h, cfg, ctx, c, layer, slot, start,
-                                   valid_len)
+                                   valid_len, attn_window)
 
-    x, cache = _layer_loop(params, x, cache, block)
+    x, cache, picks = _layer_loop(params, x, cache, block, _windows(cfg))
     cache = cache._replace(length=jnp.maximum(cache.length,
                                               start + valid_len))
     x = common.apply_norm(cfg.norm, params["ln_f"], x, cfg.norm_eps)
     last = jax.lax.dynamic_slice_in_dim(x, valid_len - 1, 1, axis=1)
     logits = common.unembed_logits(unembed_table(params, cfg), last, ctx)
-    return cache, logits
+    return (cache, logits) if picks is None else (cache, logits, picks)
 
 
 def make_cache(cfg: ModelConfig, batch: int, max_len: int,

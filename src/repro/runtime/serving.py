@@ -676,14 +676,16 @@ class ExecutorBackend:
 
     def run_chunk(self, params, row: np.ndarray, slot: int, start: int,
                   valid: int):
-        """One fixed-(1,C) prefill chunk at the slot's offset. Returns the
-        device array holding the chunk's last-valid-position argmax (the
-        first token when this was the prompt's final chunk)."""
-        self.caches, tok = self._chunk(
+        """One fixed-(1,C) prefill chunk at the slot's offset. Returns a
+        tuple of device arrays: the chunk's last-valid-position argmax (the
+        first token when this was the prompt's final chunk), then the
+        chunk's counts where the program returns any (a held-expert
+        model's picks)."""
+        self.caches, *out = self._chunk(
             params, self.caches, jnp.asarray(row[None]),
             jnp.asarray(slot, jnp.int32), jnp.asarray(start, jnp.int32),
             jnp.asarray(valid, jnp.int32))
-        return tok
+        return tuple(out)
 
     def decode_step(self, params, last_tok, positions, active):
         self.caches, nxt, new_pos = self._decode(
@@ -693,12 +695,13 @@ class ExecutorBackend:
 
     def decode_block(self, params, bucket, last_tok, positions, active,
                      remaining, eos):
-        self.caches, toks, emitted, last_d, pos_d, act_d, rem_d =\
-            self._decode_blocks[bucket](
-                params, self.caches, jnp.asarray(last_tok),
-                jnp.asarray(positions), jnp.asarray(active),
-                jnp.asarray(remaining), jnp.asarray(eos))
-        return toks, emitted, last_d, pos_d, act_d, rem_d
+        """(toks, emitted, last_tok, positions, active, remaining) of one
+        block, then the block's counts where the program returns any."""
+        self.caches, *out = self._decode_blocks[bucket](
+            params, self.caches, jnp.asarray(last_tok),
+            jnp.asarray(positions), jnp.asarray(active),
+            jnp.asarray(remaining), jnp.asarray(eos))
+        return tuple(out)
 
     def place_params(self, params):
         """``params`` placed as the step programs take them. The colocated
@@ -749,9 +752,10 @@ class ColocatedBackend(ExecutorBackend):
         tiered = isinstance(caches_aval, KVCache) and caches_aval.is_tiered
         if prefill_chunk or tiered:
             def chunk_fn(p, caches, toks, slot, start, valid):
-                caches, logits = api.prefill_chunk(p, caches, toks, slot,
-                                                   start, valid, ctx)
-                return caches, jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+                caches, logits, *counts = api.prefill_chunk(
+                    p, caches, toks, slot, start, valid, ctx)
+                return (caches, jnp.argmax(logits[:, -1], -1).astype(
+                    jnp.int32), *counts)
 
             toks_c = jnp.zeros((1, prefill_chunk or P), jnp.int32)
             self._chunk = self.rt.compile_step(
@@ -816,7 +820,7 @@ class ColocatedBackend(ExecutorBackend):
         the cold quantization and hot ring write live inside the chunk
         program)."""
         if self._prefill1 is None:
-            self.caches, tok = self._chunk(
+            self.caches, tok, *_counts = self._chunk(
                 params, self.caches, jnp.asarray(row[None]),
                 jnp.asarray(slot, jnp.int32), jnp.asarray(0, jnp.int32),
                 jnp.asarray(self.prompt_len, jnp.int32))
@@ -1520,6 +1524,10 @@ class ServingEngine:
         self._prefill_chunks = 0
         self._block_tokens: List[int] = []
         self._macro_steps = 0
+        # a held-expert model's picks per (layer, held expert), by phase,
+        # and the decode micro-steps that produced a token
+        self._expert_picks: Dict[str, np.ndarray] = {}
+        self._decode_micro_steps = 0
         # micro-batch occupancy under overlap > 1 (scheduler view)
         self._micro_batches_live = 0
         self._micro_batches_total = 0
@@ -2062,7 +2070,7 @@ class ServingEngine:
                                  slot=slot) as sp:
                 if not r.t_first_chunk:
                     r.t_first_chunk = sp.t0
-                tok = self._dispatch(ex.program_prefix + "prefill_chunk",
+                out = self._dispatch(ex.program_prefix + "prefill_chunk",
                                      ex.run_chunk, params, row, slot, start,
                                      n_valid)
         except DispatchFailure as e:
@@ -2071,9 +2079,12 @@ class ServingEngine:
             self._demote_admission(sched, slot, r, e)
             return []
         with self.spans.span("chunk_wait", rid=r.rid, slot=slot):
-            first = np.asarray(tok)               # blocks: chunk wall-time
+            # blocks: chunk wall-time
+            first, *counts = (np.asarray(a) for a in out)
         now = time.monotonic()
         self._prefill_chunks += 1
+        if counts:
+            self._count_picks("prefill", counts[0])
         finished: List[Request] = []
         if sched.chunk_done(slot, start, n_valid):
             r.t_first_token = now
@@ -2196,10 +2207,14 @@ class ServingEngine:
         return int(active.sum())
 
     def _unpack_block(self, sched: SlotScheduler, finished, toks, emitted,
-                      last_d, pos_d, act_np, rem_d) -> int:
+                      last_d, pos_d, act_np, rem_d, *counts) -> int:
         """Emit one synced block's tokens, retire the slots the device
-        halted; returns the tokens decoded."""
+        halted; returns the tokens decoded. ``counts``: the block's picks
+        per layer and held expert, where the model holds experts."""
         T = self.block_size
+        if counts:
+            self._count_picks("decode", counts[0])
+            self._decode_micro_steps += int(emitted.any(axis=1).sum())
         sched.last_tok = last_d.copy()
         sched.positions = pos_d.copy()
         sched.remaining = rem_d.copy()
@@ -2220,6 +2235,12 @@ class ServingEngine:
                 sched.retire(i)              # freed → next boundary
                 self._safe_reset(sched, i)
         return int(emitted.sum())
+
+    def _count_picks(self, phase: str, picks: np.ndarray):
+        """Add one program's picks (layers, held experts) to the run's."""
+        have = self._expert_picks.get(phase)
+        self._expert_picks[phase] = picks.astype(np.int64) if have is None \
+            else have + picks
 
     def _sample_occupancy(self, sched: SlotScheduler):
         """Counters at a decode dispatch: the chunk lane's depth and, for
@@ -2399,6 +2420,14 @@ class ServingEngine:
             out["kv"] = {"reserved_tokens": reserved,
                          "in_use_share_mean": in_use / reserved,
                          "lane_depth_mean": spans.mean("lane_depth")}
+        if self._expert_picks:
+            # token-expert picks of the held experts, per layer and expert
+            picks = self._expert_picks
+            out["moe"] = {
+                "decode_picks": picks.get("decode", np.zeros(0)).tolist(),
+                "prefill_picks": picks.get("prefill", np.zeros(0)).tolist(),
+                "picks": int(sum(int(p.sum()) for p in picks.values())),
+                "decode_micro_steps": self._decode_micro_steps}
         if self._arbiter is not None:
             # tiered-KV occupancy and placement policy: tier splits,
             # demotions counted off cursor watermarks, live/peak bytes and

@@ -99,3 +99,51 @@ def test_qwen2_decode_block_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= param_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_mellum2_share_programs_compile_for_v5e(one_chip):
+    """One chip of Mellum2-12B-A2.5B's eight-way expert parallelism at its
+    published widths (8 of 64 experts held, 21 sliding-window and 7 YaRN
+    layers): the serving engine's decode block (T=8, 32 slots, extent 4096)
+    and its 512-token chunk program, each with the picks per held expert
+    beside its outputs, fit one chip."""
+    from repro.configs.base import ModelConfig, MoEConfig, YaRNConfig
+    cfg = ModelConfig(
+        name="mellum2-12b-a2.5b-ep8", family="moe", n_layers=28,
+        d_model=2304, n_heads=32, n_kv_heads=4, d_ff=7168, vocab_size=98304,
+        head_dim=128, rope_theta=500000.0,
+        moe=MoEConfig(num_experts=64, experts_per_token=8, expert_d_ff=896,
+                      held_experts=8),
+        layer_windows=(1024, 1024, 1024, 0) * 7,
+        yarn=YaRNConfig(factor=16.0, original_max_position_embeddings=8192,
+                        attention_factor=1.2772588722239782))
+    api = build_model(cfg)
+    slots, extent, T = 32, 4096, 8
+
+    def place(tree):
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                            tree)
+
+    params = place(jax.eval_shape(api.init, jax.random.key(0)))
+    caches = place(jax.eval_shape(lambda: api.init_caches(slots, extent)))
+    i32 = _spec((slots,), jnp.int32, one_chip)
+    act = _spec((slots,), jnp.bool_, one_chip)
+    scalar = _spec((), jnp.int32, one_chip)
+
+    def block(p, c, tok, pos, act, rem, eos):
+        return api.decode_block(p, c, tok, pos, act, rem, eos, NULL_CTX,
+                                block_size=T)
+
+    def chunk(p, c, toks, slot, start, valid):
+        return api.prefill_chunk(p, c, toks, slot, start, valid, NULL_CTX)
+
+    for compiled in (
+            jax.jit(block, donate_argnums=(1,)).lower(
+                params, caches, i32, i32, act, i32, i32).compile(),
+            jax.jit(chunk, donate_argnums=(1,)).lower(
+                params, caches, _spec((1, 512), jnp.int32, one_chip),
+                scalar, scalar, scalar).compile()):
+        assert compiled.out_info[-1].shape == (28, 8)     # the picks
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 16 * 2**30
