@@ -2,14 +2,19 @@
 
 Two invariants at the bottom of the stack:
 
-  K1  flash-decode grid coverage + live kv_limit: the Pallas grid must
-      tile the FULL KV extent of every operand (an under-covering grid
+  K1  flash-decode coverage + live bounds: the Pallas grid must tile the
+      FULL extent of every blocked operand (an under-covering grid
       silently drops tail KV — attention quietly forgets the newest
-      positions), and the traced ``kv_limit`` operand must actually be
-      READ by the kernel body (a dead limit means the tile early-out — the
-      whole point of the traced operand — is gone). Checked by evaluating
-      each BlockSpec index map over every grid point and unioning the
-      covered index ranges; no TPU needed, tracing is enough.
+      positions), and the traced bounds (a ``kv_limit`` operand, or the
+      per-row ``lo``/``hi`` the kernel prefetches into SMEM) must actually
+      be READ by the kernel body (dead bounds mean the walk is no longer
+      length-aware). Checked by evaluating each BlockSpec index map over
+      every grid point and unioning the covered index ranges. K/V that
+      stay in HBM are copied tile by tile along each row's work list
+      (``live_tiles``): that map is evaluated over ragged ranges at the
+      serving tile — every fetched tile lies in [0, S), holds a position
+      of the row's range, and the tiles cover the range. No TPU needed,
+      tracing is enough.
 
   K2  chunk-write slot isolation: the chunked-prefill lane writes each
       (1, n_kv, C, hd) chunk with ``dynamic_update_slice`` at a TRACED
@@ -122,9 +127,13 @@ def _check_coverage(program: str, report: Report, op_i: int, aval,
 
 
 def _check_limit_live(program: str, report: Report, eqn, in_avals):
-    """The (1,1) int32 kv_limit operand must be consumed by the kernel."""
-    lim_idx = [i for i, a in enumerate(in_avals)
-               if tuple(a.shape) == (1, 1) and a.dtype == np.int32]
+    """The bounds must be consumed by the kernel: its scalar-prefetch
+    operands (per-row ``lo``/``hi``), else a (1,1) int32 kv_limit."""
+    n_index = getattr(eqn.params.get("grid_mapping"), "num_index_operands",
+                      0)
+    lim_idx = list(range(n_index)) or [
+        i for i, a in enumerate(in_avals)
+        if tuple(a.shape) == (1, 1) and a.dtype == np.int32]
     if not lim_idx:
         report.error(
             PASS, program, "kv_limit",
@@ -171,13 +180,60 @@ def _flash_shapes(cell: Cell) -> List[Tuple[str, int]]:
     return out
 
 
+def check_live_tile_map(program: str, S: int, block_s: int, windows,
+                        report: Report, tiles=None) -> int:
+    """Evaluate the kernel's per-row tile map (``live_tiles``, or
+    ``tiles``) over ragged ranges ``[lo, hi)`` of an ``S``-position extent
+    in tiles of ``block_s``: the empty range, one position, ends on and
+    beside tile boundaries, the whole extent, and each window's lower
+    bound. Every fetched tile must lie in [0, S // block_s) and hold a
+    position of the range, and the tiles must cover it. Returns the ranges
+    checked."""
+    if tiles is None:
+        from repro.kernels.flash_decode.flash_decode import live_tiles
+        tiles = live_tiles
+    n_tiles = S // block_s
+    ends = sorted({0, 1, 2, block_s - 1, block_s, block_s + 1, S // 2,
+                   S - block_s, S - block_s + 1, S - 1, S} & set(
+                       range(S + 1)))
+    ranges = [(0, hi) for hi in ends]
+    for w in windows:
+        if w:
+            ranges += [(max(0, hi - w), hi) for hi in ends]
+    ranges.append((S // 2, S // 4))                  # hi <= lo: nothing
+    for lo, hi in ranges:
+        first, count = (int(x) for x in tiles(np.int64(lo), np.int64(hi),
+                                              block_s))
+        visited = range(first, first + count)
+        bad = [t for t in visited if not 0 <= t < n_tiles
+               or t * block_s >= hi or (t + 1) * block_s <= lo]
+        covered = {p for t in visited for p in range(t * block_s,
+                                                     (t + 1) * block_s)}
+        if bad or not covered >= set(range(lo, hi)):
+            report.error(
+                PASS, program, f"live tiles [{lo}, {hi}) block_s={block_s}",
+                f"the row's work list visits tiles {list(visited)[:6]} of "
+                f"{n_tiles}: "
+                + (f"tile {bad[0]} lies outside [0, {n_tiles}) or outside "
+                   "the range" if bad else "positions of the range are "
+                   "never fetched"))
+            break
+    return len(ranges)
+
+
 def check_kernel_library(cell: Cell, report: Report):
     from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
+    from repro.models.transformer import live_range_tile
     caches = cell.caches_aval
     if not isinstance(caches, KVCache):
         report.info(PASS, "<kernel>", cell.spec.label,
                     "attention-free family: no flash-decode kernel")
         return
+    tile = live_range_tile(caches)
+    if tile:
+        check_live_tile_map(f"flash_decode[extent {caches.k.shape[3]}]",
+                            caches.k.shape[3], tile,
+                            getattr(cell.cfg, "layer_windows", ()), report)
     _L, B, n_kv, _S, hd = caches.k.shape
     Hq = cell.cfg.n_heads
     quant = caches.k_scale is not None

@@ -4,14 +4,17 @@
                 (LLC-streamed weights → HBM→VMEM BlockSpec streaming;
                  L1-pinned activation → VMEM-pinned activation block)
 - flash_decode: Flash-style decode attention over the contiguous KV cache
-                (KV streamed in tiles, online softmax, GQA, INT8 KV)
+                (each row's live KV range copied in tiles, online softmax,
+                GQA, INT8 KV)
 - fused_ffn:    gated-FFN fusion — both GEMVs + elementwise in one kernel so
                 weight tiles are streamed exactly once (paper Fig 6b)
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd wrapper
 with platform dispatch), ref.py (pure-jnp oracle used by tests and by the CPU
-dry-run path). The serving path calls none of them yet: its attention and
-matmuls are the jnp forms in ``models/`` and ``core/wa.py``.
+dry-run path). On a TPU the serving path's slotted decode attends through
+``flash_decode`` (``models/transformer.py::live_range_block``); its other
+attention and its matmuls are the jnp forms in ``models/`` and
+``core/wa.py``.
 """
 from __future__ import annotations
 

@@ -12,9 +12,11 @@ Two regimes, mirroring the paper's kernel split (§4.2):
   masked softmax. Under sequence-sharded KV rules the softmax reductions
   become the LSE-merge collectives (the §3.1 "add attention nodes" scaling).
 
-The Pallas TPU kernels in ``repro.kernels.flash_decode`` implement the decode
-path for real hardware; this module is the mathematically identical jnp form
-used for CPU dry-runs (DESIGN.md §10).
+The Pallas TPU kernel in ``repro.kernels.flash_decode`` serves the slotted
+decode of a flat cache on a TPU, reading each row's live range only
+(``models/transformer.py::live_range_block``); this module is the
+mathematically identical jnp form for every other read and for CPU dry-runs
+(DESIGN.md §10).
 """
 from __future__ import annotations
 

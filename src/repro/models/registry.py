@@ -58,6 +58,12 @@ class ModelAPI(NamedTuple):
     #   lane (DESIGN.md §7). slot/start/valid_len traced: zero retracing
     #   across chunks, prompts and slots. None → monolithic admission only.
     prefill_chunk: Optional[Callable] = None
+    # decode_kv_read(caches, ctx, positions, active, kv_bucket=0,
+    #                kv_shards=1) → float: KV positions one decode_slotted
+    #   micro-step reads at host cursors ``positions``, summed over rows,
+    #   mean over layers (caches may be abstract: only its structure is
+    #   read). None → the family's decode reads every slot's whole extent
+    decode_kv_read: Optional[Callable] = None
     # wa_servable: the family can serve through the WA-disaggregated backend
     #   (ServingEngine(backend="wa") → core/wa.py). True only for prefix-
     #   ordered KV-cache transformers: attention-free families have no KV to
@@ -154,12 +160,18 @@ def _build_transformer(cfg: ModelConfig) -> ModelAPI:
         return T.prefill_chunk(params, caches, tokens, slot, start,
                                valid_len, cfg, ctx)
 
+    def decode_kv_read(caches, ctx, positions, active, kv_bucket: int = 0,
+                       kv_shards: int = 1):
+        return T.decode_kv_read(cfg, caches, ctx, positions, active,
+                                kv_bucket, kv_shards)
+
     return ModelAPI(cfg, lambda k: T.init_params(k, cfg), loss, prefill,
                     decode, init_caches, _lm_input_specs(cfg),
                     decode_slotted=decode_slotted,
                     write_slot=write_slot_kv,
                     reset_slot=reset_slot,
                     decode_block=make_decode_block(decode_slotted),
+                    decode_kv_read=decode_kv_read,
                     # VLM prompts interleave vision embeds — the token-only
                     # chunk walk cannot cover them; monolithic admission only
                     prefill_chunk=None if is_vlm else prefill_chunk,

@@ -11,6 +11,7 @@ identical, the collective schedule is not.
 """
 from __future__ import annotations
 
+from collections import Counter
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -18,6 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.kernels import use_pallas_kernel
+from repro.kernels.flash_decode.flash_decode import (flash_decode_pallas,
+                                                     live_tiles)
 from repro.kv.cache import KVCache, init_kv_cache
 from repro.models import common
 from repro.models.attention import (decode_attention, flash_attention,
@@ -274,6 +278,68 @@ def _write_kv(ctx: ShardingCtx, cache: KVCache, write, rows: Tuple = (),
     return cache._replace(**dict(zip(names, out)))
 
 
+LIVE_TILE_BYTES = 256 * 1024
+
+
+def _kernel_interpret() -> Optional[bool]:
+    """How the slotted decode runs the flash-decode kernel: None where it
+    attends with the jnp form (off a TPU), else the kernel's ``interpret``
+    flag (False: the kernel runs natively on a TPU)."""
+    return False if use_pallas_kernel(None, False) else None
+
+
+def live_range_block(cache: KVCache, ctx: ShardingCtx, kv_bucket: int = 0,
+                     kv_shards: int = 1) -> int:
+    """The tile, in positions, in which the slotted decode reads each row's
+    live KV range through the flash-decode kernel; 0 where it reads every
+    row's full extent with the jnp form instead. The kernel serves a flat
+    cache (bf16, or int8 with scales) on one device, read whole: not the
+    tiered, ring, bucketed or split-KV reads, and not off a TPU. Its tile
+    copies at most ``LIVE_TILE_BYTES`` of K (all KV heads of a row at
+    once), 128 to 1024 positions that divide the extent."""
+    if (_kernel_interpret() is None or kv_bucket or kv_shards != 1
+            or (ctx.mesh is not None and ctx.mesh.size > 1)):
+        return 0
+    return live_range_tile(cache)
+
+
+def live_range_tile(cache: KVCache) -> int:
+    """``live_range_block``'s tile for a flat cache of this shape on a
+    TPU, read whole on one device; 0 for a tiered or ring cache, or an
+    extent no tile of at least 128 positions divides."""
+    if cache.is_tiered or cache.window:
+        return 0
+    _, _, n_kv, S, hd = cache.k.shape
+    per_pos = n_kv * hd * cache.k.dtype.itemsize
+    bs = 128
+    while (bs < 1024 and bs * 2 * per_pos <= LIVE_TILE_BYTES
+           and S % (bs * 2) == 0):
+        bs *= 2
+    return bs if S % bs == 0 else 0
+
+
+def decode_kv_read(cfg: ModelConfig, cache: KVCache, ctx: ShardingCtx,
+                   positions: np.ndarray, active: np.ndarray,
+                   kv_bucket: int = 0, kv_shards: int = 1) -> float:
+    """KV positions that one slotted decode micro-step at cursors
+    ``positions`` reads, summed over rows and averaged over layers: on the
+    kernel's path the positions its tiles cover (``live_tiles`` of each
+    active row's ``[lo, hi)``, a windowed layer from its lower bound), else
+    every row's whole read extent. ``cache`` may be an abstract value: only
+    its structure is read."""
+    bs = live_range_block(cache, ctx, kv_bucket, kv_shards)
+    L, B, _, S, _ = cache.k.shape
+    if not bs:
+        return float(B * (kv_bucket or S))
+    positions = np.asarray(positions, np.int64)
+    hi = np.clip(np.where(active, positions + 1, 0), 0, S)
+    total = 0
+    for w, n in Counter(cfg.layer_windows or (0,) * L).items():
+        lo = np.clip(positions + 1 - w, 0, S) if w else np.zeros_like(hi)
+        total += n * int(live_tiles(lo, hi, bs)[1].sum()) * bs
+    return total / L
+
+
 def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
                          ctx: ShardingCtx, cache: KVCache, layer,
                          positions: jax.Array, active: jax.Array,
@@ -283,12 +349,19 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
     """``block_decode`` with PER-ROW cursors (continuous batching) for layer
     ``layer`` of the carried ``cache`` stacks: row b writes its new K/V
     into the stacks in place at (layer, b, positions[b])
-    (``layer_append_slotted`` / ``layer_append_tiered``), then the layer's
-    slice is read back for attention over the row's own prefix. Inactive
-    rows write nothing (their KV stays byte-identical); their activations
-    still flow — static shapes — but the engine masks the resulting
-    logits. Returns (x', cache', picks): a held-expert layer's picks per
-    held expert over the active rows, else None.
+    (``layer_append_slotted`` / ``layer_append_tiered``), then attends
+    over the row's own prefix. Inactive rows write nothing (their KV stays
+    byte-identical); their activations still flow — static shapes — but
+    the engine masks the resulting logits. Returns (x', cache', picks): a
+    held-expert layer's picks per held expert over the active rows, else
+    None.
+
+    Where ``live_range_block`` names a tile (a flat cache on a TPU, read
+    whole), the flash-decode kernel reads each active row's live range
+    ``[lo, hi)`` (``hi`` = cursor + 1, ``lo`` the window's first position)
+    straight from the carried stacks, and an inactive row reads nothing.
+    Otherwise the layer's slice is read back and attended with the jnp
+    form over its whole extent.
 
     ``attn_window`` (traced, None for a stack of full layers): this layer
     attends only the ``attn_window`` positions ending at each row's cursor
@@ -316,7 +389,6 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
                                 layer_read_shards, layer_read_tiered,
                                 layer_read_tiered_shards)
     from repro.models.attention import decode_attention_split
-    B = x.shape[0]
     tiered = cache.is_tiered
     if window:
         kv_bucket = 0                       # ring buffers have no prefix order
@@ -340,6 +412,18 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
 
     cache = _write_kv(ctx, cache, write, (k[:, 0], v[:, 0], positions, active),
                       (layer,))
+    bs = live_range_block(cache, ctx, kv_bucket, kv_shards)
+    if bs:
+        # each active row's live range [lo, hi), read from the carried
+        # stacks in place: no layer slice is made
+        hi = jnp.where(active, positions + 1, 0)
+        lo = jnp.zeros_like(hi) if attn_window is None else jnp.where(
+            attn_window > 0, jnp.maximum(hi - attn_window, 0), 0)
+        o = flash_decode_pallas(q[:, 0], cache.k, cache.v, cache.k_scale,
+                                cache.v_scale, None, block_s=bs,
+                                interpret=_kernel_interpret(), lo=lo, hi=hi,
+                                layer=layer).astype(x.dtype)
+        return _decode_out(p, x, o, cfg, ctx, cache, active)
     k_l, v_l, ks_l, vs_l, hk_l, hv_l = _layer_slices(cache, layer, ctx)
     if tiered:
         counts = positions + 1              # append→attend: row b has p+1 toks
@@ -374,7 +458,15 @@ def block_decode_slotted(p: dict, x: jax.Array, cfg: ModelConfig,
         mask = _in_window(mask, positions[:, None], attn_window)
     attend = decode_attention_split if kv_shards > 1 else decode_attention
     o = attend(q[:, 0], kc, vc, mask, ctx)
-    o = common.linear(p["attn"]["wo"], o.reshape(B, 1, -1))
+    return _decode_out(p, x, o, cfg, ctx, cache, active)
+
+
+def _decode_out(p: dict, x: jax.Array, o: jax.Array, cfg: ModelConfig,
+                ctx: ShardingCtx, cache: KVCache, active: jax.Array
+                ) -> Tuple[jax.Array, KVCache, Optional[jax.Array]]:
+    """The rest of ``block_decode_slotted`` after attention ``o`` (B,Hq,hd):
+    output projection, FFN or held experts, residuals."""
+    o = common.linear(p["attn"]["wo"], o.reshape(x.shape[0], 1, -1))
     x = ctx.ann(x + o, "batch", "seq", "embed_shard")
     h = common.apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     h = ctx.ann(h, "batch", "seq", "embed")

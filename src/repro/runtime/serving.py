@@ -2135,6 +2135,7 @@ class ServingEngine:
                 self._micro_batches_total += 1
                 self._micro_batches_live += bool(act.any())
         if T == 1:
+            self._sample_kv_read(sched, active, 0)
             while True:
                 try:
                     with spans.span("decode_dispatch"):
@@ -2161,6 +2162,7 @@ class ServingEngine:
                     sb = bucket_for(min(needed, s_max), ex.buckets)
                 else:
                     sb = ex.buckets[0]
+                self._sample_kv_read(sched, active, sb)
                 try:
                     with spans.span("decode_dispatch"):
                         out = self._dispatch(
@@ -2256,6 +2258,22 @@ class ServingEngine:
             elif ph == sched.DECODE:
                 used += int(sched.positions[i])
         self.spans.sample("kv_in_use", used)
+
+    def _sample_kv_read(self, sched: SlotScheduler, active, bucket: int):
+        """Counter at a decode dispatch: the KV positions its first
+        micro-step reads, summed over rows and averaged over layers
+        (``ModelAPI.decode_kv_read``); the full read extent of every slot
+        where the family or the W/A backend reads it whole."""
+        if self._kv_extent is None:
+            return
+        reads = self.api.decode_kv_read if self.backend == "colocated" \
+            else None
+        if reads is None:
+            n = self.slots * (bucket or self._kv_extent)
+        else:
+            n = reads(self._caches_aval, self.ctx, sched.positions, active,
+                      bucket, self.a_shards)
+        self.spans.sample("kv_read", n)
 
     # ------------------------------------------------------------------
     def _run_drain(self, params, requests, max_steps):
@@ -2417,8 +2435,10 @@ class ServingEngine:
             # KV positions written in occupied slots, per decode dispatch,
             # against every slot's full extent
             reserved = self.slots * self._kv_extent
+            # and the positions the decode reads, over the same
             out["kv"] = {"reserved_tokens": reserved,
                          "in_use_share_mean": in_use / reserved,
+                         "read_share_mean": spans.mean("kv_read") / reserved,
                          "lane_depth_mean": spans.mean("lane_depth")}
         if self._expert_picks:
             # token-expert picks of the held experts, per layer and expert

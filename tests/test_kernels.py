@@ -11,6 +11,7 @@ import pytest
 
 from repro.kernels.flash_decode.combine import (NEG_INF, combine_partial_stats,
                                                 merge_partial_stats)
+from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
 from repro.kernels.flash_decode.ops import flash_decode, flash_decode_partial
 from repro.kernels.flash_decode.ref import (flash_decode_ref,
                                             flash_decode_ref_partial)
@@ -191,6 +192,62 @@ def test_flash_decode_sharded_partials_combine_to_full_walk():
                                 jnp.stack([p[2] for p in parts]), axis=0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# per-row live ranges [lo, hi): only the tiles a row's range touches
+# ---------------------------------------------------------------------------
+
+# (lo, hi) per row over S = 256 in tiles of 64: nothing (hi = 0), one
+# position, hi on a tile boundary, the whole extent, a range inside one
+# tile, and an empty range past a window (hi <= lo)
+RANGES = [(0, 0), (0, 1), (0, 128), (0, 256), (70, 100), (200, 150)]
+# a sliding-window lower bound: each row attends the 48 positions ending
+# at its cursor
+WINDOWED = [(0, 0), (0, 1), (80, 128), (208, 256), (17, 65), (0, 30)]
+
+
+@pytest.mark.parametrize("hd", [32, 128], ids=["hd32", "hd128"])
+@pytest.mark.parametrize("case", ["ragged", "window", "int8", "stacked"])
+def test_flash_decode_live_ranges(case, hd):
+    """Each row attends exactly [lo, hi), against the oracle with the range
+    as its mask; a row with hi <= lo returns 0. hd 32 reads the stacks
+    through their positions-minor view, hd 128 as they are."""
+    S, bs, n_kv, G, L = 256, 64, 2, 3, 3
+    lo, hi = map(jnp.asarray, zip(*(WINDOWED if case == "window"
+                                     else RANGES)))
+    B = lo.shape[0]
+    keys = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(keys[0], (B, n_kv * G, hd), jnp.float32)
+    k = jax.random.normal(keys[1], (L, B, n_kv, S, hd), jnp.float32)
+    v = jax.random.normal(keys[2], (L, B, n_kv, S, hd), jnp.float32)
+    layer = 1
+    ks = vs = None
+    if case == "int8":
+        (k, ks), (v, vs) = quantize_kv(k), quantize_kv(v)
+    kw = dict(block_s=bs, interpret=True, lo=lo, hi=hi)
+    if case == "stacked":
+        # a traced layer index into the whole stack: one compiled call
+        # serves every layer
+        call = jax.jit(lambda i: flash_decode_pallas(q, k, v, None, None,
+                                                     None, layer=i, **kw))
+        got = call(jnp.int32(layer))
+    else:
+        got = flash_decode_pallas(
+            q, k[layer], v[layer], None if ks is None else ks[layer],
+            None if vs is None else vs[layer], None, **kw)
+    kl, vl = k[layer], v[layer]
+    if case == "int8":
+        kl = kl.astype(jnp.float32) * ks[layer]
+        vl = vl.astype(jnp.float32) * vs[layer]
+    pos = jnp.arange(S)[None]
+    mask = (pos >= lo[:, None]) & (pos < hi[:, None])
+    want = flash_decode_ref(q, kl, vl, mask)
+    live = np.asarray(hi > lo)
+    np.testing.assert_allclose(np.asarray(got)[live],
+                               np.asarray(want)[live], rtol=2e-5,
+                               atol=2e-5)
+    assert not np.asarray(got)[~live].any()
 
 
 # ---------------------------------------------------------------------------

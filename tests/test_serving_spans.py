@@ -139,8 +139,12 @@ def test_kv_in_use_share_is_exact_on_a_hand_worked_serve(dense):
     assert eng.spans.samples["lane_depth"] == [1, 0, 0]
     extent = PROMPT_LEN + 16
     assert eng._kv_extent == extent
+    # off a TPU the decode attends with the jnp form: every dispatch reads
+    # both slots' whole extent
+    assert eng.spans.samples["kv_read"] == [2 * extent] * 3
     assert stats["kv"] == {"reserved_tokens": 2 * extent,
                            "in_use_share_mean": (32 / 3) / (2 * extent),
+                           "read_share_mean": 1.0,
                            "lane_depth_mean": 1 / 3}
 
 

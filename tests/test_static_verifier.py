@@ -311,6 +311,30 @@ def test_kernel_bounds_flags_dead_kv_limit():
     assert errs and "never read" in errs[0].message, rep.format(verbose=True)
 
 
+@pytest.mark.parametrize("S,bs,windows", [(4096, 512, ()),
+                                           (4096, 256, (1024, 0)),
+                                           (256, 128, (24, 0))])
+def test_live_tile_map_stays_in_each_rows_range(S, bs, windows):
+    rep = Report()
+    n = kernel_bounds.check_live_tile_map("live", S, bs, windows, rep)
+    assert n > 10 and rep.ok, rep.format(verbose=True)
+
+
+@pytest.mark.parametrize("broken,message", [
+    # a list one tile short: a range's last partial tile is never fetched
+    (lambda lo, hi, bs: (lo // bs, (hi - lo) // bs), "never fetched"),
+    # a list one tile long: past the range, and past the extent at its end
+    (lambda lo, hi, bs: (lo // bs, (hi - 1) // bs - lo // bs + 2),
+     "outside"),
+], ids=["short", "long"])
+def test_live_tile_map_flags_a_broken_map(broken, message):
+    rep = Report()
+    kernel_bounds.check_live_tile_map("broken", 1024, 128, (), rep,
+                                      tiles=broken)
+    errs = [f for f in rep.errors if f.program == "broken"]
+    assert errs and message in errs[0].message, rep.format(verbose=True)
+
+
 def test_kernel_bounds_flags_multi_slot_chunk_write(nomesh_cell):
     """A chunk program whose DUS spans 2 slots at a traced offset can alias
     a neighbour's live KV — must be an ERROR naming the write."""

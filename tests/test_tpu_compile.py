@@ -10,6 +10,8 @@ The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and under several test workers
 only the worker given this file may try.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -107,8 +109,30 @@ def test_mellum2_share_programs_compile_for_v5e(one_chip):
     layers): the serving engine's decode block (T=8, 32 slots, extent 4096)
     and its 512-token chunk program, each with the picks per held expert
     beside its outputs, fit one chip."""
+    api = build_model(_mellum2())
+    slots, extent = 32, 4096
+    params, caches = _placed(api, slots, extent, one_chip)
+    scalar = _spec((), jnp.int32, one_chip)
+
+    def chunk(p, c, toks, slot, start, valid):
+        return api.prefill_chunk(p, c, toks, slot, start, valid, NULL_CTX)
+
+    for compiled in (
+            _compile_block(api, params, caches, slots, one_chip),
+            jax.jit(chunk, donate_argnums=(1,)).lower(
+                params, caches, _spec((1, 512), jnp.int32, one_chip),
+                scalar, scalar, scalar).compile()):
+        assert compiled.out_info[-1].shape == (28, 8)     # the picks
+        mem = compiled.memory_analysis()
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 16 * 2**30
+
+
+def _mellum2():
+    """One chip of Mellum2-12B-A2.5B's eight-way expert parallelism at its
+    published widths."""
     from repro.configs.base import ModelConfig, MoEConfig, YaRNConfig
-    cfg = ModelConfig(
+    return ModelConfig(
         name="mellum2-12b-a2.5b-ep8", family="moe", n_layers=28,
         d_model=2304, n_heads=32, n_kv_heads=4, d_ff=7168, vocab_size=98304,
         head_dim=128, rope_theta=500000.0,
@@ -117,33 +141,61 @@ def test_mellum2_share_programs_compile_for_v5e(one_chip):
         layer_windows=(1024, 1024, 1024, 0) * 7,
         yarn=YaRNConfig(factor=16.0, original_max_position_embeddings=8192,
                         attention_factor=1.2772588722239782))
-    api = build_model(cfg)
-    slots, extent, T = 32, 4096, 8
 
+
+def _placed(api, slots, extent, sharding):
+    """The abstract weights and slot caches, on ``sharding``."""
     def place(tree):
-        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+        return jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding),
                             tree)
 
-    params = place(jax.eval_shape(api.init, jax.random.key(0)))
-    caches = place(jax.eval_shape(lambda: api.init_caches(slots, extent)))
-    i32 = _spec((slots,), jnp.int32, one_chip)
-    act = _spec((slots,), jnp.bool_, one_chip)
-    scalar = _spec((), jnp.int32, one_chip)
+    return (place(jax.eval_shape(api.init, jax.random.key(0))),
+            place(jax.eval_shape(lambda: api.init_caches(slots, extent))))
+
+
+def _compile_block(api, params, caches, slots, sharding, T=8):
+    """The serving engine's macro-step decode program, compiled."""
+    i32 = _spec((slots,), jnp.int32, sharding)
+    act = _spec((slots,), jnp.bool_, sharding)
 
     def block(p, c, tok, pos, act, rem, eos):
         return api.decode_block(p, c, tok, pos, act, rem, eos, NULL_CTX,
                                 block_size=T)
 
-    def chunk(p, c, toks, slot, start, valid):
-        return api.prefill_chunk(p, c, toks, slot, start, valid, NULL_CTX)
+    return jax.jit(block, donate_argnums=(1,)).lower(
+        params, caches, i32, i32, act, i32, i32).compile()
 
-    for compiled in (
-            jax.jit(block, donate_argnums=(1,)).lower(
-                params, caches, i32, i32, act, i32, i32).compile(),
-            jax.jit(chunk, donate_argnums=(1,)).lower(
-                params, caches, _spec((1, 512), jnp.int32, one_chip),
-                scalar, scalar, scalar).compile()):
-        assert compiled.out_info[-1].shape == (28, 8)     # the picks
-        mem = compiled.memory_analysis()
-        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-            < 16 * 2**30
+
+# a copy or a transpose, as an instruction or as a fusion that XLA names so
+_COPY = re.compile(r"%((?:copy|transpose)[\w.-]*) = \(?(\w+)\[([\d,]*)\]")
+
+
+@pytest.mark.parametrize("name,slots", [("qwen2-0.5b", 32),
+                                        ("internlm2-1.8b", 8),
+                                        ("mellum2-12b-a2.5b-ep8", 32)])
+def test_decode_block_reads_the_carried_stacks_for_v5e(one_chip, monkeypatch,
+                                                       name, slots):
+    """Each benchmark configuration's decode block (T=8, its slot count,
+    extent 4096) as a TPU takes it: decode attention is the flash-decode
+    kernel reading each row's live range from the carried KV stacks, and
+    the optimized program copies neither a stack nor a layer's slice of
+    one (a relayout or a materialized slice would read the whole extent
+    again), and still fits one chip."""
+    from repro.models import transformer
+    monkeypatch.setattr(transformer, "_kernel_interpret", lambda: False)
+    cfg = _mellum2() if name.startswith("mellum2") else get_config(name)
+    api = build_model(cfg)
+    params, caches = _placed(api, slots, 4096, one_chip)
+    assert transformer.live_range_block(caches, NULL_CTX) > 0
+    compiled = _compile_block(api, params, caches, slots, one_chip)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    L, B, n_kv, S, hd = caches.k.shape
+    whole = {tuple(sorted(d)) for d in ((L, B, n_kv, S, hd),
+                                        (B, n_kv, S, hd))}
+    copies = [m.group(0) for m in _COPY.finditer(text)
+              if tuple(sorted(int(d) for d in m.group(3).split(",") if d))
+              in whole]
+    assert not copies, copies[:4]
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
